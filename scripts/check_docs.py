@@ -33,7 +33,7 @@ ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 DOC_GLOBS = ("docs", "README.md", "ROADMAP.md", "CHANGES.md")
 
 # where repo CLIs define their flags (scanned for add_argument("--..."))
-CLI_SOURCE_DIRS = ("src/repro/launch", "scripts", "benchmarks")
+CLI_SOURCES = ("src/repro/launch", "scripts", "benchmarks", "chip_smoke.py")
 
 # flags the docs quote that belong to third-party tools, not repo CLIs
 THIRD_PARTY_FLAGS = {
@@ -60,14 +60,14 @@ def doc_files() -> list:
 
 def known_flags() -> set:
     flags = set(THIRD_PARTY_FLAGS)
-    for d in CLI_SOURCE_DIRS:
-        base = os.path.join(ROOT, d)
-        for root, _dirs, files in os.walk(base):
-            for f in files:
-                if not f.endswith(".py"):
-                    continue
-                with open(os.path.join(root, f)) as fh:
-                    flags.update(ADD_ARG_RE.findall(fh.read()))
+    for entry in CLI_SOURCES:
+        base = os.path.join(ROOT, entry)
+        paths = [base] if os.path.isfile(base) else [
+            os.path.join(root, f) for root, _dirs, files in os.walk(base)
+            for f in files if f.endswith(".py")]
+        for path in paths:
+            with open(path) as fh:
+                flags.update(ADD_ARG_RE.findall(fh.read()))
     return flags
 
 
@@ -96,7 +96,7 @@ def check_flags(path: str, text: str, flags: set) -> list:
             if flag not in flags:
                 errors.append(f"{os.path.relpath(path, ROOT)}:{n}: "
                               f"flag {flag} not defined by any repo CLI "
-                              f"(launchers/scripts/benchmarks)")
+                              f"(launchers/scripts/benchmarks/chip_smoke.py)")
     return errors
 
 

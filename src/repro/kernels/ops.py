@@ -1,9 +1,11 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 The model layer calls these with its own (B, S, H, D) layout; wrappers
-transpose to the kernels' (B, H, S, D) layout, choose interpret mode
-automatically off-TPU, and fall back to the jnp reference when a shape can't
-be tiled (tiny smoke configs).
+transpose to the kernels' (B, H, S, D) layout.  ``interpret=None`` picks
+from the backend: compiled Mosaic kernels on TPU, the Pallas interpreter
+anywhere else (the CPU tests).  There is no jnp fallback: a shape the TPU
+compiler refuses fails at compile time (tests/test_tpu_compile.py compiles
+the serving kernels for a described v5e chip).
 """
 from __future__ import annotations
 

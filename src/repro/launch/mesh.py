@@ -16,28 +16,18 @@ import math
 import jax
 import numpy as np
 
-from repro.compat import AxisType, HAS_AXIS_TYPE
-from repro.compat import make_mesh as compat_make_mesh
+from jax.sharding import AxisType, Mesh
 
 
 def _mesh(device_arr, axes):
-    """``Mesh`` over an explicit device array, Auto axis types where the
-    jax lineage has them (0.4.x predates the enum — plain Mesh there)."""
-    from jax.sharding import Mesh
-    if HAS_AXIS_TYPE:
-        try:
-            return Mesh(device_arr, axes,
-                        axis_types=(AxisType.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return Mesh(device_arr, axes)
+    """``Mesh`` over an explicit device array with Auto axis types."""
+    return Mesh(device_arr, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes,
-                            axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_serve_mesh(shape):
@@ -77,11 +67,10 @@ def make_job_mesh(n_chips: int, *, n_pods: int = 1, max_model: int = 16):
         model *= 2
     data = per_pod // model
     if n_pods > 1:
-        return compat_make_mesh((n_pods, data, model),
-                                ("pod", "data", "model"),
-                                axis_types=(AxisType.Auto,) * 3)
-    return compat_make_mesh((data, model), ("data", "model"),
-                            axis_types=(AxisType.Auto,) * 2)
+        return jax.make_mesh((n_pods, data, model), ("pod", "data", "model"),
+                             axis_types=(AxisType.Auto,) * 3)
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def submesh_for_placement(placement, cluster, devices=None, *,

@@ -59,18 +59,27 @@ queue-depth|slo-backlog`` attaches an elastic ``runtime.autoscale``
 ``--scale-cooldown`` anti-flap freeze); ``--max-replicas`` above a
 role's initial count provisions cold DOWN spares for scale-up to
 rejoin.  See docs/disagg_autoscale.md.
+
+The runtime knobs come from the platform (``serving_knobs``): bf16 with
+the Pallas kernels on a TPU, bf16 on XLA attention when a mesh is set,
+float32 XLA elsewhere.  JAX's persistent compilation cache goes to
+``$JAX_COMPILATION_CACHE_DIR`` when set, else to ``<checkout>/.jax_cache``.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, list_archs
+from repro.launch.mesh import make_serve_mesh
+from repro.launch.pool_fit import fit_device_pool
 from repro.models import LM, RuntimeKnobs
 from repro.runtime.autoscale import AUTOSCALE_POLICIES, Autoscaler
 from repro.runtime.cluster import ROUTER_POLICIES, ClusterRouter
@@ -81,6 +90,74 @@ from repro.runtime.scheduler import ADMISSION_POLICIES, VICTIM_POLICIES
 from repro.runtime.serve import (Request, SamplingParams, ServeConfig,
                                  ServeEngine)
 from repro.runtime.telemetry import Telemetry
+from repro.sharding import serve_param_shardings
+
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def serving_knobs(backend: str, *, sharded: bool) -> RuntimeKnobs:
+    """The serving knobs for what this process runs on.
+
+    * ``backend == "tpu"``, one device: bf16 params, compute and KV cache
+      with the Pallas kernels.
+    * ``backend == "tpu"`` with a mesh: bf16 on XLA attention — the Pallas
+      kernels are single-device and ``ServeEngine`` refuses them there.
+    * any other backend (the CPU tests): float32 params, compute and KV
+      cache on XLA, which keeps cache round trips bit-exact.
+    """
+    if backend != "tpu":
+        return RuntimeKnobs(cache_dtype=jnp.float32)
+    return RuntimeKnobs(param_dtype=jnp.bfloat16, compute_dtype=jnp.bfloat16,
+                        cache_dtype=jnp.bfloat16, use_pallas=not sharded)
+
+
+def build_serving_model(cfg, *, mesh_shape=None):
+    """``(model, params)`` as the launcher serves them: knobs from
+    ``serving_knobs`` for ``jax.default_backend()``, params drawn on the
+    device from seed 0.  With ``mesh_shape`` the params are created in
+    the sharded engine's layout (``serve_param_shardings``), so no device
+    ever holds a full unsharded copy."""
+    sharded = mesh_shape is not None
+    model = LM(cfg, serving_knobs(jax.default_backend(), sharded=sharded))
+    out_shardings = None
+    if sharded:
+        out_shardings = serve_param_shardings(
+            make_serve_mesh(mesh_shape), cfg, model.param_specs())
+    init = jax.jit(model.init, out_shardings=out_shardings)
+    return model, init(jax.random.PRNGKey(0))
+
+
+def fitted_num_pages(model, serve_cfg: ServeConfig):
+    """The page pool one engine asks for when ``--num-pages`` is not
+    given.  For a paged engine on one TPU: the largest pool whose compiled
+    steps fit the device (``pool_fit.fit_device_pool``, as
+    ``chip_smoke.py`` sizes it).  Elsewhere ``serve_cfg.num_pages``
+    unchanged (``None`` is the engine's dense-equivalent default)."""
+    if (serve_cfg.num_pages is not None or serve_cfg.cache != "paged"
+            or serve_cfg.mesh_shape is not None
+            or jax.default_backend() != "tpu"):
+        return serve_cfg.num_pages
+    if serve_cfg.kv_dtype:
+        model = LM(model.cfg, model.knobs.with_(kv_quant=serve_cfg.kv_dtype))
+    fit = fit_device_pool(model, jax.devices()[0],
+                          slots=serve_cfg.batch_slots,
+                          max_len=serve_cfg.max_len,
+                          page_size=serve_cfg.page_size,
+                          chunk=serve_cfg.prefill_chunk)
+    return fit.num_pages
+
+
+def enable_compile_cache(root=REPO_ROOT) -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory: ``$JAX_COMPILATION_CACHE_DIR`` when set (JAX reads the
+    variable itself), else the fixed ``<root>/.jax_cache``.  Call at
+    program start, before the first compile: JAX decides once whether a
+    process uses the cache."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(root) / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def parse_tenant_weights(spec: str) -> dict:
@@ -290,9 +367,9 @@ def main():
             ap.error(f"--scale-cooldown must be >= 0 "
                      f"(got {args.scale_cooldown})")
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
-    model = LM(cfg, RuntimeKnobs(cache_dtype=jnp.float32))
-    params = model.init(jax.random.PRNGKey(0))
+    model, params = build_serving_model(cfg, mesh_shape=mesh_shape)
     serve_cfg = ServeConfig(
         batch_slots=args.slots, max_len=args.max_len, mode=args.mode,
         prefill_chunk=args.prefill_chunk, cache=args.cache,
@@ -303,6 +380,12 @@ def main():
         victim_policy=args.victim_policy,
         draft_k=args.draft_k if args.speculate else 0,
         drafter=args.drafter, mesh_shape=mesh_shape)
+    if args.roles is None and args.replicas == 1:
+        # replicas would each hold a pool on the same device (ROADMAP R6)
+        num_pages = fitted_num_pages(model, serve_cfg)
+        if num_pages != serve_cfg.num_pages:
+            print(f"paged pool fitted to the device: {num_pages} pages")
+            serve_cfg = dataclasses.replace(serve_cfg, num_pages=num_pages)
 
     tm = Telemetry(trace=bool(args.trace_out) or args.flight_recorder > 0,
                    flight=args.flight_recorder, flight_dir="artifacts")

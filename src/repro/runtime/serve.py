@@ -104,6 +104,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import functools
 import time
 import warnings
 from collections import deque
@@ -499,7 +500,8 @@ class ServeEngine:
             # the pool may round capacity up (num_hosts alignment): size
             # the device pools from what it actually holds, never the ask
             num_pages = self.kv.pool.num_pages
-            self.caches = model.init_cache_paged(num_pages, page_size)
+            make_caches = functools.partial(model.init_cache_paged,
+                                            num_pages, page_size)
             # greedy and sampled variants both exist (jit is lazy — only
             # the ones a trace actually hits compile); a tick pays the
             # sampling math only when a live slot has temperature > 0
@@ -535,7 +537,8 @@ class ServeEngine:
                     model, "paged_prefill_chunk_buf_gather",
                     page_size=page_size, sampled=True)
         else:
-            self.caches = model.init_cache(batch_slots, max_len)
+            make_caches = functools.partial(model.init_cache, batch_slots,
+                                            max_len)
             self._step = compiled_step(model, "serve")
             self._step_sampled = compiled_step(model, "serve", sampled=True)
             # chunked prefill: one compiled (1, C) step reused for every
@@ -577,9 +580,16 @@ class ServeEngine:
             # (serve_cache_shardings — NOT the training cache rules,
             # which shard the sequence dim and would psum softmax stats)
             cache_shardings = serve_cache_shardings(
-                mesh, self.caches, paged=(config.cache == "paged"))
-        if cache_shardings is not None:
-            self.caches = jax.device_put(self.caches, cache_shardings)
+                mesh, jax.eval_shape(make_caches),
+                paged=(config.cache == "paged"))
+        # a sharded cache is created in its layout: no device ever holds
+        # the whole pool, which may not fit one device
+        self.caches = (make_caches() if cache_shardings is None else
+                       jax.jit(make_caches, out_shardings=cache_shardings)())
+        if mesh is not None and self._pf_buf is not None:
+            # the XLA prefill's slot view splits its KV heads like the pool
+            self._pf_buf = jax.device_put(
+                self._pf_buf, serve_cache_shardings(mesh, self._pf_buf))
         # decide/execute split: the scheduler owns the queue, the policy,
         # the per-tenant (weighted) DRF accounting, and the preemption
         # victim policy — host state only

@@ -20,8 +20,7 @@ sys.path.insert(0, "src")
 import dataclasses
 import jax, jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import AxisType, make_mesh as compat_make_mesh
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.configs import get_config
 from repro.models import LM, RuntimeKnobs
 from repro.optim import AdamWConfig
@@ -50,8 +49,8 @@ def run_sub(body: str, timeout=560):
 
 def test_sharded_train_step_runs_and_matches_single_device():
     out = run_sub("""
-        mesh = compat_make_mesh((2, 4), ("data", "model"),
-                               axis_types=(AxisType.Auto,) * 2)
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         model = tiny_model(mesh)
         cfg = model.cfg
         state = init_train_state(model, jax.random.PRNGKey(0))
@@ -86,10 +85,10 @@ def test_sharded_train_step_runs_and_matches_single_device():
 def test_elastic_checkpoint_restore_across_mesh_shapes():
     out = run_sub("""
         from repro.checkpoint import restore, save_checkpoint
-        mesh_a = compat_make_mesh((2, 4), ("data", "model"),
-                                 axis_types=(AxisType.Auto,) * 2)
-        mesh_b = compat_make_mesh((4, 2), ("data", "model"),
-                                 axis_types=(AxisType.Auto,) * 2)
+        mesh_a = jax.make_mesh((2, 4), ("data", "model"),
+                               axis_types=(AxisType.Auto,) * 2)
+        mesh_b = jax.make_mesh((4, 2), ("data", "model"),
+                               axis_types=(AxisType.Auto,) * 2)
         model = tiny_model(mesh_a)
         cfg = model.cfg
         specs = train_state_specs(model)
@@ -116,8 +115,8 @@ def test_mini_dryrun_with_serve_step_and_roofline():
     out = run_sub("""
         from repro.launch.roofline import analyze_hlo, roofline
         from repro.runtime.steps import make_serve_step
-        mesh = compat_make_mesh((2, 4), ("data", "model"),
-                               axis_types=(AxisType.Auto,) * 2)
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         model = tiny_model(mesh)
         cfg = model.cfg
         pspecs = model.param_specs()

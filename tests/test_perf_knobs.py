@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from repro.compat import AxisType, abstract_mesh
+from jax.sharding import AbstractMesh, AxisType
 
 from repro.configs import get_config
 from repro.kernels import attention_ref
@@ -65,8 +65,8 @@ def test_model_with_causal_skip_trains():
 
 # -------------------------------------------------- H3: pure-DP layout
 def _mesh():
-    return abstract_mesh((16, 16), ("data", "model"),
-                         axis_types=(AxisType.Auto,) * 2)
+    return AbstractMesh((16, 16), ("data", "model"),
+                        axis_types=(AxisType.Auto,) * 2)
 
 
 def test_dp_layout_replicates_params_keeps_opt_sharded():
@@ -95,8 +95,8 @@ def test_dp_layout_batch_uses_all_axes():
 
 # ------------------------------------- H1: data-only ZeRO-2 grad shardings
 def test_grad_shardings_never_use_pod_axis():
-    mesh = abstract_mesh((2, 16, 16), ("pod", "data", "model"),
-                         axis_types=(AxisType.Auto,) * 3)
+    mesh = AbstractMesh((2, 16, 16), ("pod", "data", "model"),
+                        axis_types=(AxisType.Auto,) * 3)
     cfg = get_config("qwen3-moe-235b-a22b")
     model = LM(cfg, RuntimeKnobs(param_dtype=jnp.bfloat16))
     specs = model.param_specs()

@@ -12,7 +12,7 @@ PROG = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
     import numpy as np
-    from repro.compat import AxisType, make_mesh
+    from jax.sharding import AxisType
     from repro.sharding.pipeline import make_pipelined_forward
 
     S, LPS, M, MB, D = 4, 2, 6, 3, 8   # 4 stages x 2 layers, 6 microbatches
@@ -37,8 +37,8 @@ PROG = textwrap.dedent("""
             h = stage_fn({"w": w[s], "b": b[s]}, h)
         return h
 
-    mesh = make_mesh((S,), ("stage",),
-                     axis_types=(AxisType.Explicit,))
+    mesh = jax.make_mesh((S,), ("stage",),
+                         axis_types=(AxisType.Explicit,))
     # leading dim S is sharded over the stage axis; shard_map's local view
     # keeps it as a singleton that pipeline_apply's p[0] strips
     fwd = make_pipelined_forward(stage_fn, mesh, axis_name="stage")

@@ -104,6 +104,32 @@ def test_sharded_spec_decode_bitwise_identical():
         """)
 
 
+def test_sharded_engine_creates_its_kv_state_split():
+    """A mesh engine's KV state (dense stripes, or the paged pool and the
+    XLA prefill's slot buffer) spans every mesh device with its KV heads
+    split, before and after serving, and is never parked on one device
+    (a pool sized for the mesh may not fit one)."""
+    run_sub("""
+        for cache in ("dense", "paged"):
+            m = tiny_model()
+            eng = ServeEngine(m, m.init(jax.random.PRNGKey(0)), ServeConfig(
+                batch_slots=4, max_len=64, cache=cache, mesh_shape=(1, 2)))
+            mesh_devs = set(eng.mesh.devices.flat)
+            for when in ("built", "served"):
+                state = [eng.caches, eng._pf_buf]
+                for leaf in jax.tree.leaves(state):
+                    assert leaf.sharding.device_set == mesh_devs, (
+                        cache, when, leaf.shape, leaf.sharding)
+                    assert not leaf.sharding.is_fully_replicated, (
+                        cache, when, leaf.shape, leaf.sharding)
+                assert (eng._pf_buf is None) == (cache == "dense")
+                for r in requests():
+                    eng.submit(r)
+                eng.run(max_ticks=500)
+        print("layout OK")
+        """)
+
+
 def test_sharded_offer_reports_per_host_pages():
     """Regression: a sharded paged engine's offer() advertises the
     per-host sub-pool split, it sums to the aggregate, and an admitted
@@ -136,11 +162,11 @@ def test_serve_cache_shardings_on_paged_specs():
     KV-head over model) — never the in-page sequence dim — and dense
     stripes to (slot over data, KV-head over model)."""
     run_sub("""
-        from repro.compat import AxisType, make_mesh as compat_make_mesh
+        from jax.sharding import AxisType
         from repro.sharding import (ServeShardFn, serve_cache_shardings,
                                     serve_param_shardings)
-        mesh = compat_make_mesh((2, 2), ("data", "model"),
-                                axis_types=(AxisType.Auto,) * 2)
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         m = tiny_model()
         paged = jax.eval_shape(lambda: m.init_cache_paged(8, 16))
         sh = serve_cache_shardings(mesh, paged, paged=True)

@@ -6,7 +6,7 @@ import textwrap
 import jax
 import jax.numpy as jnp
 import pytest
-from repro.compat import AxisType, abstract_mesh
+from jax.sharding import AbstractMesh, AxisType
 
 from repro.configs import get_config, list_archs
 from repro.launch.roofline import analyze_hlo, roofline
@@ -15,7 +15,7 @@ from repro.sharding import opt_state_shardings, param_shardings
 
 
 def _mesh(shape, axes):
-    return abstract_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return AbstractMesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 @pytest.mark.parametrize("arch", list_archs())
@@ -71,10 +71,10 @@ _PROBE = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.compat import AxisType, make_mesh
+    from jax.sharding import AxisType
 
-    mesh = make_mesh((2, 4), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
     L, M, K, N = 7, 64, 32, 16
 
