@@ -27,6 +27,16 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+@jax.named_scope("kv_layout")
+def _kernel_layout(k_pages, v_pages, k_scale, v_scale):
+    """Page pools (and scales, if any) from the model's (P, page_size, KV,
+    D) into the paged kernels' (P, KV, page_size, D)."""
+    def swap(x):
+        return None if x is None else x.swapaxes(1, 2)
+
+    return swap(k_pages), swap(v_pages), swap(k_scale), swap(v_scale)
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=0, block_q=512,
@@ -89,10 +99,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_idx, pos, *, active=None,
     """
     interpret = (not _on_tpu()) if interpret is None else interpret
     qt = q.swapaxes(1, 2)
-    kt = k_pages.swapaxes(1, 2)
-    vt = v_pages.swapaxes(1, 2)
-    kst = k_scale.swapaxes(1, 2) if k_scale is not None else None
-    vst = v_scale.swapaxes(1, 2) if v_scale is not None else None
+    kt, vt, kst, vst = _kernel_layout(k_pages, v_pages, k_scale, v_scale)
     if num_splits > 1 and q.shape[1] == 1:
         out = paged_decode_attention_splitk_tpu(
             qt, kt, vt, page_idx, pos, active=active, window=window,
@@ -119,10 +126,7 @@ def paged_prefill_attention(q, k_pages, v_pages, page_idx, slot, offset, *,
     """
     interpret = (not _on_tpu()) if interpret is None else interpret
     qt = q.swapaxes(1, 2)
-    kt = k_pages.swapaxes(1, 2)
-    vt = v_pages.swapaxes(1, 2)
-    kst = k_scale.swapaxes(1, 2) if k_scale is not None else None
-    vst = v_scale.swapaxes(1, 2) if v_scale is not None else None
+    kt, vt, kst, vst = _kernel_layout(k_pages, v_pages, k_scale, v_scale)
     page_row = jnp.take(jnp.asarray(page_idx, jnp.int32), slot, axis=0)
     out = paged_prefill_attention_tpu(qt, kt, vt, page_row, offset,
                                       window=window, k_scale=kst,

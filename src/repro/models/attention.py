@@ -251,6 +251,7 @@ def dequantize_kv(q, scale):
     return q.astype(jnp.float32) * scale
 
 
+@jax.named_scope("kv_write")
 def paged_cache_update_quant(k_pages, v_pages, k_scale, v_scale, k_new,
                              v_new, pos, page_idx, page_size):
     """Quantized ``paged_cache_update``: quantize the fresh (B,1,KV,D)
@@ -268,6 +269,7 @@ def paged_cache_update_quant(k_pages, v_pages, k_scale, v_scale, k_new,
     return k_pages, v_pages, k_scale, v_scale
 
 
+@jax.named_scope("kv_write")
 def paged_prefill_chunk_update_quant(k_pages, v_pages, k_scale, v_scale,
                                      k_new, v_new, slot, offset, page_idx,
                                      page_size):
@@ -282,6 +284,7 @@ def paged_prefill_chunk_update_quant(k_pages, v_pages, k_scale, v_scale,
     return k_pages, v_pages, k_scale, v_scale
 
 
+@jax.named_scope("kv_write")
 def paged_cache_update_multi_quant(k_pages, v_pages, k_scale, v_scale,
                                    k_new, v_new, pos, page_idx, page_size):
     """Quantized ``paged_cache_update_multi`` (speculative verify
@@ -295,6 +298,7 @@ def paged_cache_update_multi_quant(k_pages, v_pages, k_scale, v_scale,
     return k_pages, v_pages, k_scale, v_scale
 
 
+@jax.named_scope("kv_write")
 def paged_cache_update(k_pages, v_pages, k_new, v_new, pos, page_idx,
                        page_size):
     """Insert (B,1,KV,D) at logical position ``pos`` through the page
@@ -317,6 +321,7 @@ def paged_cache_update(k_pages, v_pages, k_new, v_new, pos, page_idx,
     return k_pages, v_pages
 
 
+@jax.named_scope("kv_write")
 def paged_prefill_chunk_update(k_pages, v_pages, k_new, v_new, slot, offset,
                                page_idx, page_size):
     """Write one slot's prompt chunk (1, C, KV, D), C a multiple of
@@ -358,6 +363,7 @@ def gather_slot_pages(k_pages, v_pages, page_idx, slot, k_scale=None,
     return k, v
 
 
+@jax.named_scope("kv_write")
 def paged_cache_update_multi(k_pages, v_pages, k_new, v_new, pos, page_idx,
                              page_size):
     """Insert a (B,T,KV,D) draft block at logical positions ``pos[b] + t``
@@ -391,6 +397,7 @@ def paged_cache_update_multi(k_pages, v_pages, k_new, v_new, pos, page_idx,
     return k_pages, v_pages
 
 
+@jax.named_scope("kv_write")
 def cache_update(k_cache, v_cache, k_new, v_new, pos):
     """Insert (B,1,KV,D) at position ``pos`` of (B,S,KV,D) caches.
 
@@ -413,6 +420,7 @@ def cache_update(k_cache, v_cache, k_new, v_new, pos):
     return upd(k_cache, k_new, pos), upd(v_cache, v_new, pos)
 
 
+@jax.named_scope("kv_write")
 def cache_update_multi(k_cache, v_cache, k_new, v_new, pos):
     """Insert a (B,T,KV,D) draft block at positions ``pos[b] + t`` of
     (B,S,KV,D) caches — the multi-token ``cache_update``.

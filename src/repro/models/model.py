@@ -123,6 +123,12 @@ class LM:
         return logits.astype(jnp.float32), caches
 
     # ------------------------------------------------------------- decode
+    def _unembed(self, params, x):
+        """Final norm and unembedding of the cached (serving) paths,
+        under the device program's ``unembed`` scope."""
+        with jax.named_scope("unembed"):
+            return unembed(params["embed"], rmsnorm(params["final_norm"], x))
+
     def decode_step(self, params, caches, tokens, pos):
         """tokens (B,1) int32 -> (logits (B,V), new caches).
 
@@ -133,8 +139,7 @@ class LM:
         x = embed(params["embed"], tokens).astype(self.knobs.compute_dtype)
         x, new_caches = apply_blocks_decode(params["blocks"], x, caches, pos,
                                             cfg=self.cfg, knobs=self.knobs)
-        x = rmsnorm(params["final_norm"], x)
-        logits = unembed(params["embed"], x)[:, 0, :]
+        logits = self._unembed(params, x)[:, 0, :]
         return logits.astype(jnp.float32), new_caches
 
     def prefill_chunk_step(self, params, caches, tokens, slot, offset):
@@ -149,8 +154,7 @@ class LM:
         x, new_caches = apply_blocks_prefill_chunk(
             params["blocks"], x, caches, slot, offset, cfg=self.cfg,
             knobs=self.knobs)
-        x = rmsnorm(params["final_norm"], x)
-        logits = unembed(params["embed"], x)[0]
+        logits = self._unembed(params, x)[0]
         return logits.astype(jnp.float32), new_caches
 
     def supports_chunked_prefill(self) -> bool:
@@ -178,8 +182,7 @@ class LM:
         x = embed(params["embed"], tokens).astype(self.knobs.compute_dtype)
         x, new_caches = apply_blocks_decode(params["blocks"], x, caches, pos,
                                             cfg=self.cfg, knobs=self.knobs)
-        x = rmsnorm(params["final_norm"], x)
-        logits = unembed(params["embed"], x)
+        logits = self._unembed(params, x)
         return logits.astype(jnp.float32), new_caches
 
     def decode_step_spec_paged(self, params, caches, tokens, pos, page_idx,
@@ -192,8 +195,7 @@ class LM:
         x, new_caches = apply_blocks_decode(params["blocks"], x, caches, pos,
                                             cfg=self.cfg, knobs=self.knobs,
                                             paged=(page_idx, page_size))
-        x = rmsnorm(params["final_norm"], x)
-        logits = unembed(params["embed"], x)
+        logits = self._unembed(params, x)
         return logits.astype(jnp.float32), new_caches
 
     # -------------------------------------------------------- paged cache
@@ -209,8 +211,7 @@ class LM:
         x, new_caches = apply_blocks_decode(params["blocks"], x, caches, pos,
                                             cfg=self.cfg, knobs=self.knobs,
                                             paged=(page_idx, page_size))
-        x = rmsnorm(params["final_norm"], x)
-        logits = unembed(params["embed"], x)[:, 0, :]
+        logits = self._unembed(params, x)[:, 0, :]
         return logits.astype(jnp.float32), new_caches
 
     def prefill_chunk_step_paged(self, params, caches, tokens, slot, offset,
@@ -222,8 +223,7 @@ class LM:
         x, new_caches = apply_blocks_prefill_chunk(
             params["blocks"], x, caches, slot, offset, cfg=self.cfg,
             knobs=self.knobs, paged=(page_idx, page_size))
-        x = rmsnorm(params["final_norm"], x)
-        logits = unembed(params["embed"], x)[0]
+        logits = self._unembed(params, x)[0]
         return logits.astype(jnp.float32), new_caches
 
     def prefill_chunk_step_paged_buf(self, params, caches, tokens, slot,
@@ -241,8 +241,7 @@ class LM:
             params["blocks"], x, merged, slot, offset, cfg=self.cfg,
             knobs=self.knobs, paged=(page_idx, page_size), gather=gather)
         new_caches, new_buf = unzip_prefill_buf(new_merged)
-        x = rmsnorm(params["final_norm"], x)
-        logits = unembed(params["embed"], x)[0]
+        logits = self._unembed(params, x)[0]
         return logits.astype(jnp.float32), new_caches, new_buf
 
     def copy_cache_pages(self, caches, src, dst):

@@ -174,18 +174,30 @@ def _apply_attn_block(p, x, positions, *, cfg, window, knobs, collect_cache,
     return x, aux, cache
 
 
-def _ffn_out(p, h2, ffn, *, cfg, shard_fn):
-    """Inference-time FFN tail shared by the cached block bodies."""
+@jax.named_scope("mlp")
+def _ffn_out(p, x, ffn, *, cfg, shard_fn):
+    """Inference-time FFN sublayer (norm, FFN, residual) shared by the
+    cached block bodies."""
+    h2 = rmsnorm(p["ln2"], x)
     if ffn == "moe":
         out, _ = moe_ffn(p["moe"], h2, cfg.moe, train=False, shard_fn=shard_fn)
-        return out
-    if ffn == "mlp":
-        return mlp(p["mlp"], h2, cfg.gated_mlp, shard_fn=shard_fn)
-    return jnp.zeros_like(h2)
+    elif ffn == "mlp":
+        out = mlp(p["mlp"], h2, cfg.gated_mlp, shard_fn=shard_fn)
+    else:
+        out = jnp.zeros_like(h2)
+    return x + out
 
 
 def _apply_attn_block_decode(p, x, cache, pos, *, cfg, window, knobs, ffn,
                              shard_fn, paged=None):
+    x, new_cache = _attn_decode(p, x, cache, pos, cfg=cfg, window=window,
+                                knobs=knobs, shard_fn=shard_fn, paged=paged)
+    return _ffn_out(p, x, ffn, cfg=cfg, shard_fn=shard_fn), new_cache
+
+
+@jax.named_scope("attention")
+def _attn_decode(p, x, cache, pos, *, cfg, window, knobs, shard_fn,
+                 paged=None):
     """``paged = (page_idx, page_size)`` switches the cache from a dense
     per-slot stripe to a shared page pool addressed through the slot's
     page-table row; attention masking is identical either way.
@@ -242,14 +254,21 @@ def _apply_attn_block_decode(p, x, cache, pos, *, cfg, window, knobs, ffn,
         else:
             ctx = attn.decode_attention_xla(q, kc, vc, pos, window=window)
     ctx = shard_fn("attn_out", ctx)
-    x = x + attn.attn_output(p["attn"], ctx)
-    h2 = rmsnorm(p["ln2"], x)
-    return x + _ffn_out(p, h2, ffn, cfg=cfg, shard_fn=shard_fn), new_cache
+    return x + attn.attn_output(p["attn"], ctx), new_cache
 
 
 def _apply_attn_block_prefill_chunk(p, x, cache, slot, offset, *, cfg, window,
                                     knobs, ffn, shard_fn, paged=None,
                                     gather=False):
+    x, new_cache = _attn_prefill_chunk(
+        p, x, cache, slot, offset, cfg=cfg, window=window, knobs=knobs,
+        shard_fn=shard_fn, paged=paged, gather=gather)
+    return _ffn_out(p, x, ffn, cfg=cfg, shard_fn=shard_fn), new_cache
+
+
+@jax.named_scope("attention")
+def _attn_prefill_chunk(p, x, cache, slot, offset, *, cfg, window, knobs,
+                        shard_fn, paged=None, gather=False):
     """One slot's prompt chunk: x (1,C,dm) at absolute positions
     offset..offset+C-1.  Writes the chunk's K/V into cache[slot] in place,
     then runs blocked flash attention of the chunk against the slot's full
@@ -326,12 +345,13 @@ def _apply_attn_block_prefill_chunk(p, x, cache, slot, offset, *, cfg, window,
                                            q_chunk=min(knobs.q_chunk, c),
                                            q_offset=offset)
     else:
-        kc = jax.lax.dynamic_update_slice(cache["k"],
-                                          k_new.astype(cache["k"].dtype),
-                                          (slot, offset, 0, 0))
-        vc = jax.lax.dynamic_update_slice(cache["v"],
-                                          v_new.astype(cache["v"].dtype),
-                                          (slot, offset, 0, 0))
+        with jax.named_scope("kv_write"):
+            kc = jax.lax.dynamic_update_slice(
+                cache["k"], k_new.astype(cache["k"].dtype),
+                (slot, offset, 0, 0))
+            vc = jax.lax.dynamic_update_slice(
+                cache["v"], v_new.astype(cache["v"].dtype),
+                (slot, offset, 0, 0))
         new_cache = {"k": kc, "v": vc}
         k_slot = jax.lax.dynamic_slice_in_dim(kc, slot, 1, axis=0)
         v_slot = jax.lax.dynamic_slice_in_dim(vc, slot, 1, axis=0)
@@ -340,9 +360,7 @@ def _apply_attn_block_prefill_chunk(p, x, cache, slot, offset, *, cfg, window,
                                        q_chunk=min(knobs.q_chunk, c),
                                        q_offset=offset)
     ctx = shard_fn("attn_out", ctx)
-    x = x + attn.attn_output(p["attn"], ctx)
-    h2 = rmsnorm(p["ln2"], x)
-    return x + _ffn_out(p, h2, ffn, cfg=cfg, shard_fn=shard_fn), new_cache
+    return x + attn.attn_output(p["attn"], ctx), new_cache
 
 
 def _apply_ssm_block(p, x, *, cfg, collect_cache, shard_fn,
@@ -445,6 +463,10 @@ def _walk_plan_cached(blocks, x, caches, *, cfg, inner_fn, outer_fn):
     plan = build_plan(cfg)
     ffn = _ffn_kind(cfg)
 
+    # the scan slices each layer's parameters and KV pools out of the
+    # stacks and writes the layer's pools back: ops of the ``layer_scan``
+    # scope outside ``attention``/``mlp`` are that traffic
+    @jax.named_scope("layer_scan")
     def scan_stack(stack, cstack, xx, window):
         def body(c, inp):
             p, cache = inp
@@ -468,7 +490,8 @@ def _walk_plan_cached(blocks, x, caches, *, cfg, inner_fn, outer_fn):
     xs = {"inner": blocks["inner"]}
     if not plan.outer_shared:
         xs["outer"] = blocks["outer"]
-    x, new_g = jax.lax.scan(group_body, x, (xs, caches["groups"]))
+    with jax.named_scope("layer_scan"):
+        x, new_g = jax.lax.scan(group_body, x, (xs, caches["groups"]))
     new_caches = {"groups": new_g}
     if plan.remainder:
         x, new_rem = scan_stack(blocks["rem"], caches["rem"], x,

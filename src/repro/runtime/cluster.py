@@ -257,10 +257,10 @@ class ReplicaHandle:
         wall time exactly as it would a genuinely straggling host."""
         if self.engine is None:
             return 0
-        if tick <= self.stall_until and self.stall_s > 0:
-            time.sleep(self.stall_s)
         flagged_before = len(self.watchdog.flagged)
         self.watchdog.start()
+        if tick <= self.stall_until and self.stall_s > 0:
+            time.sleep(self.stall_s)
         emitted = self.engine.step()
         self.watchdog(tick, None)
         self.steps += 1

@@ -267,7 +267,9 @@ class PrefixCache:
     Keys chain-hash each ``page_size``-token chunk with its parent's key,
     so a hit on chunk *i* implies chunks 0..i-1 all matched.  The cache
     holds one refcount per entry; entries whose page refcount has dropped
-    to 1 (cache-only) are evictable, LRU order.
+    to 1 (cache-only) are evictable, LRU order.  ``hits`` / ``misses``
+    count pages matched and chains broken short, once per admission that
+    succeeds (``KVCacheManager.admit``), never per refused probe.
     """
 
     def __init__(self, pool: PagePool):
@@ -321,13 +323,11 @@ class PrefixCache:
             key = _chunk_key(parent, prompt[i * ps:(i + 1) * ps])
             page = self._map.get(key)
             if page is None:
-                self.misses += 1
                 break
             self._map.move_to_end(key)
             self.pool.incref(page)
             pages.append(page)
             parent = key
-            self.hits += 1
         return pages, len(pages) * ps
 
     def insert(self, prompt: np.ndarray, blocks: list[int]):
@@ -446,9 +446,10 @@ class KVCacheManager:
                  lambda: self.pool.in_use),
                 ("kv_prefix_entries", "prefix-cache chains resident",
                  lambda: 0 if self.prefix is None else len(self.prefix)),
-                ("kv_prefix_hits", "prefix-cache probe hits",
+                ("kv_prefix_hits", "prefix-cache pages reused, per admission",
                  lambda: 0 if self.prefix is None else self.prefix.hits),
-                ("kv_prefix_misses", "prefix-cache probe misses",
+                ("kv_prefix_misses",
+                 "admissions whose cached prefix broke short of the prompt",
                  lambda: 0 if self.prefix is None else self.prefix.misses)):
             registry.gauge(name, help, ("replica",)).labels(
                 **lbl).set_function(fn)
@@ -569,6 +570,11 @@ class KVCacheManager:
                 self.pool.decref(pg)
             return None
         fresh = self.pool.alloc(need_new, host=host)
+        if self.prefix is not None:
+            # counted here, once: a refused admission is looked up again
+            # on every retry
+            self.prefix.hits += len(cached)
+            self.prefix.misses += int(len(cached) < p // ps)
         blocks = list(cached)
         cow = []
         for blk in cow_blocks:

@@ -119,6 +119,13 @@ def _topk_topp_mask(scaled, top_k, top_p):
     return jnp.take_along_axis(mask_sorted, inv, axis=-1)
 
 
+def greedy_tokens(logits):
+    """Argmax over the last (vocabulary) axis as int32: the greedy pick
+    of every serving step, under the device program's ``sample`` scope."""
+    with jax.named_scope("sample"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 def sample_tokens(logits, pos, temp, top_k, top_p, keys):
     """Sample (or greedily pick) one token per row, static shapes.
 
@@ -128,17 +135,18 @@ def sample_tokens(logits, pos, temp, top_k, top_p, keys):
     uint32 raw PRNG key data.  Rows with ``temp <= 0`` return the raw-logit
     argmax — bitwise the greedy path, untouched by the sampling math.
     """
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    safe_t = jnp.maximum(temp, 1e-6)[:, None]
-    scaled = (logits / safe_t).astype(jnp.float32)
-    masked = scaled + _topk_topp_mask(scaled, top_k, top_p)
+    with jax.named_scope("sample"):
+        greedy = greedy_tokens(logits)
+        safe_t = jnp.maximum(temp, 1e-6)[:, None]
+        scaled = (logits / safe_t).astype(jnp.float32)
+        masked = scaled + _topk_topp_mask(scaled, top_k, top_p)
 
-    def draw(key, p, row):
-        return jax.random.categorical(
-            jax.random.fold_in(key, jnp.maximum(p, 0)), row)
+        def draw(key, p, row):
+            return jax.random.categorical(
+                jax.random.fold_in(key, jnp.maximum(p, 0)), row)
 
-    sampled = jax.vmap(draw)(keys, pos, masked).astype(jnp.int32)
-    return jnp.where(temp > 0, sampled, greedy)
+        sampled = jax.vmap(draw)(keys, pos, masked).astype(jnp.int32)
+        return jnp.where(temp > 0, sampled, greedy)
 
 
 def sample_tokens_multi(logits, pos, temp, top_k, top_p, keys):

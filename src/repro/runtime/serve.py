@@ -121,7 +121,7 @@ from repro.runtime.sampling import (SamplingParams, matches_stop,
                                     sample_tokens, speculative_accept)
 from repro.runtime.scheduler import Scheduler
 from repro.runtime.steps import (compiled_fn, compiled_step,
-                                 pick_decode_splits, step_cache_stats)
+                                 pick_decode_splits)
 from repro.runtime.telemetry import Telemetry
 
 __all__ = ["Checkpoint", "Request", "RequestHandle", "RequestState",
@@ -143,6 +143,15 @@ def request_metrics(req: "Request") -> dict:
             and len(req.output) > 1:
         out["tpot_s"] = (req.t_finish - req.t_first) / (len(req.output) - 1)
     return out
+
+
+def _abstract(x):
+    """An array's shape and dtype, and its layout where it is split over
+    devices, for lowering a step again as the call compiled it."""
+    sharding = getattr(x, "sharding", None)
+    if sharding is not None and len(sharding.device_set) == 1:
+        sharding = None
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
 
 def _ckpt_fns(model, max_len: int):
@@ -457,6 +466,8 @@ class ServeEngine:
         # jitted steps come from runtime.steps' module-level LRU: engines
         # over equal (cfg, knobs) share one compiled callable per step
         self._decode_one = compiled_step(model, "decode_one")
+        # compiled step -> the abstract arguments of its first call
+        self._ran: dict = {}
         # checkpoint/restore (dense): built on first preemption
         self._copy_out = self._copy_in = None
         self.kv: Optional[KVCacheManager] = None
@@ -669,6 +680,27 @@ class ServeEngine:
         req.state = state
         self.tm.req_transition(self.replica, req.req_id, state.name, **args)
 
+    def _span(self, name: str, **stats):
+        """One tick phase on this replica's engine row
+        (``Telemetry.span``)."""
+        return self.tm.span(name, pid=self.replica, **stats)
+
+    def _run(self, step, *args):
+        """Call a compiled step, noting the shapes of its first call
+        (``step_hlo_texts``)."""
+        if step not in self._ran:
+            self._ran[step] = jax.tree.map(_abstract, args)
+        return step(*args)
+
+    def step_hlo_texts(self) -> list:
+        """The optimized HLO text of every compiled step this engine has
+        run, compiled again from the shapes of its first call (the
+        program comes back from the compile cache where one is set).  A
+        profile's device ops name their HLO instructions; these texts
+        give each one its ``op_name``, the program's named scopes."""
+        return [step.lower(*args).compile().as_text()
+                for step, args in self._ran.items()]
+
     def _tick_telemetry(self, emitted: int) -> None:
         """Per-tick accounting: counters always (two float adds), plus a
         Chrome counter-track sample of the engine's vitals when tracing
@@ -686,7 +718,6 @@ class ServeEngine:
         if self.draft_k:
             vals["spec_proposed"] = self.spec_proposed
             vals["spec_accepted"] = self.spec_accepted
-        vals["step_cache_hits"] = step_cache_stats()["hits"]
         tr.counter(self.replica, "engine", vals)
 
     @property
@@ -899,11 +930,13 @@ class ServeEngine:
         the same tick, hence the loop).  Preemptions execute first: a
         slot must be checkpointed before its next occupant prefills."""
         while True:
-            plan = self.scheduler.decide(self.active)
+            with self._span("engine.admit") as sp:
+                plan = self.scheduler.decide(self.active)
+                for pre in plan.preemptions:
+                    self._execute_preemption(pre)
+                sp.set(admitted=len(plan.admissions))
             if not plan:
                 return
-            for pre in plan.preemptions:
-                self._execute_preemption(pre)
             for adm in plan.admissions:
                 self._execute_admission(adm)
 
@@ -921,58 +954,62 @@ class ServeEngine:
         prompt = np.asarray(req.prompt, np.int32)
         p = len(prompt)
         n_chunks = max(1, -(-(p - start) // c))
-        padded = np.zeros(n_chunks * c, np.int32)
-        padded[:p - start] = prompt[start:]
-        req._feed = deque()  # type: ignore
-        sp = req.sampling
-        sampling = sp.temperature > 0
-        extra = (() if self.kv is None
-                 else (jnp.asarray(self.kv.page_table),))
-        samp = (() if not sampling else
-                (jnp.float32(sp.temperature), jnp.int32(sp.top_k),
-                 jnp.float32(sp.top_p),
-                 jnp.asarray(sp.key_data(req.req_id))))
-        prefill = self._prefill_sampled if sampling else self._prefill
-        nxt = None
-        for ci in range(n_chunks):
-            last = (p - start - 1) - ci * c  # final-chunk row of the
-            last_row = last if 0 <= last < c else 0  # last real token
-            chunk = jnp.asarray(padded[None, ci * c:(ci + 1) * c])
-            if self._pf_buf is not None:
-                # buffered paged prefill: thread the dense slot view
-                # through the chunk loop; a prefix-cache hit rebuilds it
-                # from the page table on the first chunk only
-                fn = prefill
-                if ci == 0 and start > 0:
-                    fn = (self._prefill_gather_sampled if sampling
-                          else self._prefill_gather)
-                if sampling:
-                    nxt, self.caches, self._pf_buf = fn(
-                        self.params, self.caches, chunk, jnp.int32(s),
-                        jnp.int32(start + ci * c), *extra, self._pf_buf,
+        with self._span("engine.prefill", slot=s, tokens=p - start,
+                        chunks=n_chunks):
+            padded = np.zeros(n_chunks * c, np.int32)
+            padded[:p - start] = prompt[start:]
+            req._feed = deque()  # type: ignore
+            sp = req.sampling
+            sampling = sp.temperature > 0
+            extra = (() if self.kv is None
+                     else (jnp.asarray(self.kv.page_table),))
+            samp = (() if not sampling else
+                    (jnp.float32(sp.temperature), jnp.int32(sp.top_k),
+                     jnp.float32(sp.top_p),
+                     jnp.asarray(sp.key_data(req.req_id))))
+            prefill = self._prefill_sampled if sampling else self._prefill
+            nxt = None
+            for ci in range(n_chunks):
+                last = (p - start - 1) - ci * c  # final-chunk row of the
+                last_row = last if 0 <= last < c else 0  # last real token
+                chunk = jnp.asarray(padded[None, ci * c:(ci + 1) * c])
+                if self._pf_buf is not None:
+                    # buffered paged prefill: thread the dense slot view
+                    # through the chunk loop; a prefix-cache hit rebuilds it
+                    # from the page table on the first chunk only
+                    fn = prefill
+                    if ci == 0 and start > 0:
+                        fn = (self._prefill_gather_sampled if sampling
+                              else self._prefill_gather)
+                    if sampling:
+                        nxt, self.caches, self._pf_buf = self._run(
+                            fn, self.params, self.caches, chunk,
+                            jnp.int32(s), jnp.int32(start + ci * c), *extra,
+                            self._pf_buf, jnp.int32(last_row), *samp)
+                    else:
+                        nxt, self.caches, self._pf_buf = self._run(
+                            fn, self.params, self.caches, chunk,
+                            jnp.int32(s), jnp.int32(start + ci * c), *extra,
+                            self._pf_buf)
+                elif sampling:
+                    nxt, self.caches = self._run(
+                        prefill, self.params, self.caches, chunk,
+                        jnp.int32(s), jnp.int32(start + ci * c), *extra,
                         jnp.int32(last_row), *samp)
                 else:
-                    nxt, self.caches, self._pf_buf = fn(
-                        self.params, self.caches, chunk, jnp.int32(s),
-                        jnp.int32(start + ci * c), *extra, self._pf_buf)
-            elif sampling:
-                nxt, self.caches = prefill(
-                    self.params, self.caches, chunk, jnp.int32(s),
-                    jnp.int32(start + ci * c), *extra,
-                    jnp.int32(last_row), *samp)
-            else:
-                nxt, self.caches = prefill(
-                    self.params, self.caches, chunk, jnp.int32(s),
-                    jnp.int32(start + ci * c), *extra)
-        tok = (int(np.asarray(nxt)) if sampling
-               else int(np.asarray(nxt)[(p - start - 1)
-                                        - (n_chunks - 1) * c]))
-        self.pos[s] = p
-        self.tokens[s, 0] = tok
-        self._emit(req, tok)
-        self._admit_emitted += 1
-        if self.kv is not None:
-            self.kv.register_prefix(s, prompt)
+                    nxt, self.caches = self._run(
+                        prefill, self.params, self.caches, chunk,
+                        jnp.int32(s), jnp.int32(start + ci * c), *extra)
+            with self._span("engine.sync"):
+                tok = (int(np.asarray(nxt)) if sampling
+                       else int(np.asarray(nxt)[(p - start - 1)
+                                                - (n_chunks - 1) * c]))
+            self.pos[s] = p
+            self.tokens[s, 0] = tok
+            self._emit(req, tok)
+            self._admit_emitted += 1
+            if self.kv is not None:
+                self.kv.register_prefix(s, prompt)
 
     def _maybe_stop(self, s: int) -> bool:
         req = self.active[s]
@@ -996,7 +1033,10 @@ class ServeEngine:
         self.caches = jax.tree.map(lambda c: jnp.zeros_like(c), self.caches)
         self.pos[:] = 0
         self.tokens[:] = 0
-        for adm in self.scheduler.decide(self.active).admissions:
+        with self._span("engine.admit") as sp:
+            plan = self.scheduler.decide(self.active)
+            sp.set(admitted=len(plan.admissions))
+        for adm in plan.admissions:
             s, req = adm.slot, adm.req
             self.active[s] = req
             sp = req.sampling
@@ -1011,11 +1051,12 @@ class ServeEngine:
     # ------------------------------------------------------------ stepping
     def step(self) -> int:
         """One engine tick = one decode step for every live slot."""
-        if self.mode == "wave":
-            emitted = self._step_wave()
-        else:
-            emitted = self._step_continuous()
-        self._tick_telemetry(emitted)
+        with self._span("engine.step"):
+            if self.mode == "wave":
+                emitted = self._step_wave()
+            else:
+                emitted = self._step_continuous()
+            self._tick_telemetry(emitted)
         return emitted
 
     def _put_b(self, x):
@@ -1057,9 +1098,10 @@ class ServeEngine:
         live = sum(r is not None for r in self.active)
         if not live:
             return emitted
-        if self.draft_k:
-            return self._decode_tick_spec(emitted, live)
-        return self._decode_tick_plain(emitted, live)
+        with self._span("engine.decode", live=live):
+            if self.draft_k:
+                return self._decode_tick_spec(emitted, live)
+            return self._decode_tick_plain(emitted, live)
 
     def _decode_tick_plain(self, emitted: int, live: int) -> int:
         """One single-token decode step for every live slot (the
@@ -1067,47 +1109,48 @@ class ServeEngine:
         ticks where no slot proposed a draft — the T-wide verify step
         would pay ~T x attention/unembed work to emit the same one
         token per slot)."""
-        pos = self._put_b(self.pos)
-        # pay the sampling math only when a live slot actually samples
-        # (finished slots reset their temp to 0)
-        sampling = bool(self.samp_temp.max() > 0)
-        samp = (() if not sampling else
-                (self._put_b(self.samp_temp), self._put_b(self.samp_topk),
-                 self._put_b(self.samp_topp), self._put_b(self.samp_keys)))
-        if self.kv is not None:
+        with self._span("engine.dispatch") as sp:
+            pos = self._put_b(self.pos)
+            # pay the sampling math only when a live slot actually samples
+            # (finished slots reset their temp to 0)
+            sampling = bool(self.samp_temp.max() > 0)
+            samp = (() if not sampling else
+                    (self._put_b(self.samp_temp), self._put_b(self.samp_topk),
+                     self._put_b(self.samp_topp), self._put_b(self.samp_keys)))
             step = self._step_sampled if sampling else self._step
+            splits = max(self.model.knobs.decode_splits, 1)
             if self._autotune:
-                step = self._step_for_splits(pick_decode_splits(
+                splits = pick_decode_splits(
                     int(self.pos.max()), live, max_len=self.max_len,
-                    page_size=self.config.page_size), sampling)
-            nxt_dev, self.caches = step(
-                self.params, self.caches, self._put_b(self.tokens), pos,
-                jnp.asarray(self.kv.page_table), *samp)
-        else:
-            step = self._step_sampled if sampling else self._step
-            if self._autotune:
-                step = self._step_for_splits(pick_decode_splits(
-                    int(self.pos.max()), live, max_len=self.max_len),
-                    sampling)
-            nxt_dev, self.caches = step(self.params, self.caches,
-                                        self._put_b(self.tokens), pos,
-                                        *samp)
-        nxt = np.asarray(nxt_dev)
-        for s, req in enumerate(self.active):
-            if req is None:
-                continue
-            self.pos[s] += 1
-            feed = getattr(req, "_feed")
-            if feed:  # still consuming the prompt (token-feed path)
-                self.tokens[s, 0] = feed.popleft()
-                continue
-            if req.state is RequestState.PREFILL:  # token-feed path done
-                self._set_state(req, RequestState.DECODE)
-            tok = int(nxt[s, 0])
-            self._emit(req, tok)
-            emitted += 1
-            self.tokens[s, 0] = tok
-            self._maybe_stop(s)
+                    page_size=(0 if self.kv is None
+                               else self.config.page_size))
+                step = self._step_for_splits(splits, sampling)
+            sp.set(splits=splits)
+            extra = (() if self.kv is None
+                     else (jnp.asarray(self.kv.page_table),))
+            nxt_dev, self.caches = self._run(
+                step, self.params, self.caches, self._put_b(self.tokens),
+                pos, *extra, *samp)
+        with self._span("engine.sync"):
+            nxt = np.asarray(nxt_dev)
+        with self._span("engine.emit") as sp:
+            before = emitted
+            for s, req in enumerate(self.active):
+                if req is None:
+                    continue
+                self.pos[s] += 1
+                feed = getattr(req, "_feed")
+                if feed:  # still consuming the prompt (token-feed path)
+                    self.tokens[s, 0] = feed.popleft()
+                    continue
+                if req.state is RequestState.PREFILL:  # token-feed done
+                    self._set_state(req, RequestState.DECODE)
+                tok = int(nxt[s, 0])
+                self._emit(req, tok)
+                emitted += 1
+                self.tokens[s, 0] = tok
+                self._maybe_stop(s)
+            sp.set(emitted=emitted - before)
         return emitted
 
     # ------------------------------------------------------- speculative
@@ -1179,42 +1222,48 @@ class ServeEngine:
                 draft_len[s] = len(d)
         if not draft_len.any():
             return self._decode_tick_plain(emitted, live)
-        pos = self._put_b(self.pos)
-        sampling = bool(self.samp_temp.max() > 0)
-        samp = (() if not sampling else
-                (self._put_b(self.samp_temp), self._put_b(self.samp_topk),
-                 self._put_b(self.samp_topp), self._put_b(self.samp_keys)))
-        step = self._spec_step_sampled if sampling else self._spec_step
-        extra = (() if self.kv is None
-                 else (jnp.asarray(self.kv.page_table),))
-        target_dev, self.caches = step(self.params, self.caches,
-                                       self._put_b(feed), pos, *extra, *samp)
-        target = np.asarray(target_dev)  # (B, T) per-row verified tokens
+        with self._span("engine.dispatch", splits=1):
+            pos = self._put_b(self.pos)
+            sampling = bool(self.samp_temp.max() > 0)
+            samp = (() if not sampling else
+                    (self._put_b(self.samp_temp), self._put_b(self.samp_topk),
+                     self._put_b(self.samp_topp), self._put_b(self.samp_keys)))
+            step = self._spec_step_sampled if sampling else self._spec_step
+            extra = (() if self.kv is None
+                     else (jnp.asarray(self.kv.page_table),))
+            target_dev, self.caches = self._run(
+                step, self.params, self.caches, self._put_b(feed), pos,
+                *extra, *samp)
+        with self._span("engine.sync"):
+            target = np.asarray(target_dev)  # (B, T) per-row verified
         self.spec_ticks += 1
-        for s, req in enumerate(self.active):
-            if req is None:
-                continue
-            fq = getattr(req, "_feed")
-            if fq:  # still consuming the prompt (token-feed path)
-                self.pos[s] += 1
-                self.tokens[s, 0] = fq.popleft()
-                continue
-            if req.state is RequestState.PREFILL:  # token-feed path done
-                self._set_state(req, RequestState.DECODE)
-            k_s = int(draft_len[s])
-            m = (speculative_accept(feed[s, 1:1 + k_s], target[s, :k_s])
-                 if k_s else 0)
-            self.spec_proposed += k_s
-            self.spec_accepted += m
-            for t in range(m + 1):
-                self.pos[s] += 1
-                tok = int(target[s, t])
-                self._emit(req, tok)
-                emitted += 1
-                self.spec_emitted += 1
-                self.tokens[s, 0] = tok
-                if self._maybe_stop(s):
-                    break  # accepted-but-past-stop tokens are discarded
+        with self._span("engine.emit") as sp:
+            before = emitted
+            for s, req in enumerate(self.active):
+                if req is None:
+                    continue
+                fq = getattr(req, "_feed")
+                if fq:  # still consuming the prompt (token-feed path)
+                    self.pos[s] += 1
+                    self.tokens[s, 0] = fq.popleft()
+                    continue
+                if req.state is RequestState.PREFILL:  # token-feed done
+                    self._set_state(req, RequestState.DECODE)
+                k_s = int(draft_len[s])
+                m = (speculative_accept(feed[s, 1:1 + k_s], target[s, :k_s])
+                     if k_s else 0)
+                self.spec_proposed += k_s
+                self.spec_accepted += m
+                for t in range(m + 1):
+                    self.pos[s] += 1
+                    tok = int(target[s, t])
+                    self._emit(req, tok)
+                    emitted += 1
+                    self.spec_emitted += 1
+                    self.tokens[s, 0] = tok
+                    if self._maybe_stop(s):
+                        break  # accepted-but-past-stop tokens discarded
+            sp.set(emitted=emitted - before)
         return emitted
 
     def spec_stats(self) -> dict:
@@ -1243,43 +1292,54 @@ class ServeEngine:
 
     def _step_wave(self) -> int:
         self._admit_wave()
-        if not any(r is not None for r in self.active):
+        live = sum(r is not None for r in self.active)
+        if not live:
             return 0
-        pos = int(self.pos.max())  # lockstep position (wave batching)
-        logits, self.caches = self._decode_one(self.params, self.caches,
-                                               jnp.asarray(self.tokens),
-                                               jnp.int32(pos))
-        if bool(self.samp_temp.max() > 0):
-            # sampled wave mode: host-side draw from the wave logits.
-            # Slots advance in lockstep from position 0, so each slot's
-            # absolute token position IS the wave position — the same
-            # (key, position) fold as the continuous sampled step, hence
-            # the same trajectory for a given seed; greedy (temp 0) rows
-            # stay the bitwise argmax inside sample_tokens.
-            sampler = compiled_fn(("wave_sample",), lambda: sample_tokens)
-            nxt = np.asarray(sampler(
-                logits, jnp.asarray(self.pos),
-                jnp.asarray(self.samp_temp), jnp.asarray(self.samp_topk),
-                jnp.asarray(self.samp_topp), jnp.asarray(self.samp_keys)),
-                dtype=np.int32)
-        else:
-            nxt = np.asarray(jnp.argmax(logits, axis=-1), dtype=np.int32)
+        with self._span("engine.decode", live=live):
+            return self._decode_tick_wave()
+
+    def _decode_tick_wave(self) -> int:
+        with self._span("engine.dispatch", splits=1):
+            pos = int(self.pos.max())  # lockstep position (wave batching)
+            logits, self.caches = self._run(
+                self._decode_one, self.params, self.caches,
+                jnp.asarray(self.tokens), jnp.int32(pos))
+            if bool(self.samp_temp.max() > 0):
+                # sampled wave mode: host-side draw from the wave logits.
+                # Slots advance in lockstep from position 0, so each
+                # slot's absolute token position IS the wave position —
+                # the same (key, position) fold as the continuous sampled
+                # step, hence the same trajectory for a given seed; greedy
+                # (temp 0) rows stay the bitwise argmax inside
+                # sample_tokens.
+                sampler = compiled_fn(("wave_sample",),
+                                      lambda: sample_tokens)
+                nxt_dev = self._run(
+                    sampler, logits, jnp.asarray(self.pos),
+                    jnp.asarray(self.samp_temp), jnp.asarray(self.samp_topk),
+                    jnp.asarray(self.samp_topp), jnp.asarray(self.samp_keys))
+            else:
+                nxt_dev = jnp.argmax(logits, axis=-1)
+        with self._span("engine.sync"):
+            nxt = np.asarray(nxt_dev, dtype=np.int32)
         emitted = 0
-        for s, req in enumerate(self.active):
-            if req is None:
-                continue
-            self.pos[s] += 1
-            feed = getattr(req, "_feed")
-            if feed:  # still consuming the prompt
-                self.tokens[s, 0] = feed.popleft()
-                continue
-            if req.state is RequestState.PREFILL:
-                self._set_state(req, RequestState.DECODE)
-            tok = int(nxt[s])
-            self._emit(req, tok)
-            emitted += 1
-            self.tokens[s, 0] = tok
-            self._maybe_stop(s)
+        with self._span("engine.emit") as sp:
+            for s, req in enumerate(self.active):
+                if req is None:
+                    continue
+                self.pos[s] += 1
+                feed = getattr(req, "_feed")
+                if feed:  # still consuming the prompt
+                    self.tokens[s, 0] = feed.popleft()
+                    continue
+                if req.state is RequestState.PREFILL:
+                    self._set_state(req, RequestState.DECODE)
+                tok = int(nxt[s])
+                self._emit(req, tok)
+                emitted += 1
+                self.tokens[s, 0] = tok
+                self._maybe_stop(s)
+            sp.set(emitted=emitted)
         return emitted
 
     # --------------------------------------------------------- cluster hooks

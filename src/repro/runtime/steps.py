@@ -12,7 +12,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.optim import AdamWConfig, adamw_init, adamw_update, warmup_cosine
-from repro.runtime.sampling import sample_tokens, sample_tokens_multi
+from repro.runtime.sampling import (greedy_tokens, sample_tokens,
+                                    sample_tokens_multi)
 
 
 def init_train_state(model, rng, moments_dtype=jnp.float32) -> dict:
@@ -88,7 +89,7 @@ def make_train_step(model, opt_cfg: AdamWConfig, grad_accum: int = 1,
 def make_prefill_step(model) -> Callable:
     def prefill_step(params, batch):
         logits, caches = model.prefill(params, batch)
-        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+        next_tokens = greedy_tokens(logits)[:, None]
         return next_tokens, caches
 
     return prefill_step
@@ -106,7 +107,7 @@ def make_serve_step(model, sampled: bool = False) -> Callable:
     any mix of greedy and sampled requests."""
     def serve_step(params, caches, tokens, pos):
         logits, new_caches = model.decode_step(params, caches, tokens, pos)
-        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+        next_tokens = greedy_tokens(logits)[:, None]
         return next_tokens, new_caches
 
     def sampled_serve_step(params, caches, tokens, pos, temp, top_k, top_p,
@@ -134,7 +135,7 @@ def make_prefill_chunk_step(model, sampled: bool = False) -> Callable:
     def prefill_chunk_step(params, caches, tokens, slot, offset):
         logits, new_caches = model.prefill_chunk_step(params, caches, tokens,
                                                       slot, offset)
-        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        next_tokens = greedy_tokens(logits)
         return next_tokens, new_caches
 
     def sampled_chunk_step(params, caches, tokens, slot, offset, last_row,
@@ -164,7 +165,7 @@ def make_paged_prefill_chunk_buf_step(model, page_size: int,
         logits, new_caches, new_buf = model.prefill_chunk_step_paged_buf(
             params, caches, tokens, slot, offset, page_idx, buf,
             page_size=page_size, gather=gather)
-        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        next_tokens = greedy_tokens(logits)
         return next_tokens, new_caches, new_buf
 
     def sampled_chunk_step(params, caches, tokens, slot, offset, page_idx,
@@ -199,7 +200,7 @@ def make_spec_serve_step(model, draft_len: int,
     def spec_step(params, caches, tokens, pos):
         logits, new_caches = model.decode_step_spec(params, caches, tokens,
                                                     pos)
-        target = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        target = greedy_tokens(logits)
         return target, new_caches
 
     def sampled_spec_step(params, caches, tokens, pos, temp, top_k, top_p,
@@ -219,7 +220,7 @@ def make_paged_spec_serve_step(model, page_size: int, draft_len: int,
     def spec_step(params, caches, tokens, pos, page_idx):
         logits, new_caches = model.decode_step_spec_paged(
             params, caches, tokens, pos, page_idx, page_size=page_size)
-        target = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        target = greedy_tokens(logits)
         return target, new_caches
 
     def sampled_spec_step(params, caches, tokens, pos, page_idx, temp,
@@ -243,7 +244,7 @@ def make_paged_serve_step(model, page_size: int,
         logits, new_caches = model.decode_step_paged(params, caches, tokens,
                                                      pos, page_idx,
                                                      page_size=page_size)
-        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
+        next_tokens = greedy_tokens(logits)[:, None]
         return next_tokens, new_caches
 
     def sampled_serve_step(params, caches, tokens, pos, page_idx, temp,
@@ -267,7 +268,7 @@ def make_paged_prefill_chunk_step(model, page_size: int,
         logits, new_caches = model.prefill_chunk_step_paged(
             params, caches, tokens, slot, offset, page_idx,
             page_size=page_size)
-        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        next_tokens = greedy_tokens(logits)
         return next_tokens, new_caches
 
     def sampled_chunk_step(params, caches, tokens, slot, offset, page_idx,

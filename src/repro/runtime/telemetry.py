@@ -19,12 +19,13 @@ This module gives the stack one spine, in three layers:
     Structured events in Chrome trace-event form (open
     ``chrome://tracing`` or https://ui.perfetto.dev on the JSON):
     per-request lifecycle spans (QUEUED → PREFILL → DECODE, with
-    PREEMPTED / REPLAY sub-spans), per-tick engine counter tracks (live
-    slots, queue depth, free pages, draft acceptance, step-cache hits),
-    and router instants (heartbeat misses, LOST/fence, placement,
-    straggler route-around, brown-out).  ``pid`` is the replica id
-    (router events use ``ROUTER_PID``), ``tid`` the request id, so
-    Perfetto renders one track per replica and one row per request.
+    PREEMPTED / REPLAY sub-spans), the engine's tick phases
+    (``ENGINE_SPANS`` on the ``ENGINE_TID`` row), per-tick engine counter
+    tracks (live slots, queue depth, free pages, draft acceptance), and
+    router instants (heartbeat misses, LOST/fence, placement, straggler
+    route-around, brown-out).  ``pid`` is the replica id (router events
+    use ``ROUTER_PID``), ``tid`` the request id, so Perfetto renders one
+    track per replica and one row per request.
     **Invariant — span pairing**: every ``begin_span`` is closed by
     exactly one matching ``end_span`` on the same ``(pid, tid)`` track,
     in LIFO order within the track; open spans are tracked per
@@ -42,8 +43,11 @@ This module gives the stack one spine, in three layers:
     The facade the engine/router/launcher bind to: always carries a
     real registry (cheap), and either a live ``TraceRecorder`` or the
     shared ``NULL_TRACE`` no-op — the null-sink fast path that makes
-    disabled tracing cost near zero (gated at ≤2% tokens/s overhead
-    *with tracing fully on* in ``benchmarks/serve_throughput.py``).
+    disabled tracing cost near zero.  ``span(name, **stats)`` times one
+    engine phase on the profiler's clock (a ``jax.profiler.
+    TraceAnnotation``, recorded only while a profiler session runs, on
+    the same timeline as the device's ops) and, when the recorder is
+    live, as B/E events on the engine row.
     ``dump_flight(reason)`` writes the last ``flight`` events plus a
     full metrics snapshot to ``flight_dir`` — ``ClusterRouter`` calls
     it automatically on fence/retry-exhaustion, so every chaos anomaly
@@ -61,11 +65,19 @@ import time
 from collections import deque
 from typing import Callable, Iterable, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["MetricsRegistry", "TraceRecorder", "NullTrace", "NULL_TRACE",
-           "Telemetry", "ROUTER_PID", "validate_chrome_trace"]
+           "Telemetry", "ROUTER_PID", "ENGINE_TID", "ENGINE_SPANS",
+           "validate_chrome_trace"]
 
 ROUTER_PID = 10_000  # trace track for cluster-router events (pid space
 #                      0..N-1 belongs to the engine replicas)
+ENGINE_TID = -1  # row of the engine's tick phases (request ids are >= 0)
+# the engine's tick phases, outermost first (docs/observability.md)
+ENGINE_SPANS = ("engine.step", "engine.admit", "engine.prefill",
+                "engine.decode", "engine.dispatch", "engine.sync",
+                "engine.emit")
 
 _DEFAULT_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
                     0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
@@ -418,6 +430,38 @@ NULL_TRACE = NullTrace()
 
 
 # ------------------------------------------------------------------ facade
+class Span:
+    """One ``Telemetry.span``.  ``set(**stats)`` adds stats known only at
+    the span's end (how many were admitted, emitted)."""
+
+    __slots__ = ("_note", "_trace", "_pid", "_name", "_stats", "_args")
+
+    def __init__(self, trace, pid: int, name: str, stats: dict):
+        self._note = TraceAnnotation(name, **stats)
+        self._trace = trace
+        self._pid = pid
+        self._name = name
+        self._stats = stats
+        self._args = {}
+
+    def set(self, **stats) -> None:
+        self._note.set_metadata(**stats)
+        self._args.update(stats)
+
+    def __enter__(self) -> "Span":
+        self._note.__enter__()
+        if self._trace.enabled:
+            self._trace.begin(self._pid, ENGINE_TID, self._name,
+                              **self._stats)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._trace.enabled:
+            self._trace.end(self._pid, ENGINE_TID, **self._args)
+        self._note.__exit__(*exc)
+        return False
+
+
 class Telemetry:
     """What the engine / router / launcher bind to.
 
@@ -442,6 +486,15 @@ class Telemetry:
         self.flight = int(flight)
         self.flight_dir = flight_dir
         self.flight_dumps: list[str] = []
+
+    # ------------------------------------------------------- tick phases
+    def span(self, name: str, *, pid: int = 0, **stats) -> Span:
+        """Context manager timing one engine phase (``ENGINE_SPANS``;
+        ``stats`` are small ints).  Always a ``TraceAnnotation``: it
+        records only while a profiler session runs, and then lands on
+        the host plane of the same trace as the device's ops.  With the
+        recorder live it is also a B/E pair on ``(pid, ENGINE_TID)``."""
+        return Span(self.trace, pid, name, stats)
 
     # ------------------------------------------------- request lifecycle
     def req_transition(self, pid: int, req_id: int, state: str,

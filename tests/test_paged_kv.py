@@ -302,6 +302,28 @@ def test_manager_eviction_under_pressure():
     assert m.pool.in_use == 5
 
 
+def test_prefix_hits_count_once_per_admission_not_per_refusal():
+    """An admission refused for lack of pages is looked up again on every
+    retry; its prefix hits count once, when it is admitted."""
+    m = KVCacheManager(slots=3, max_len=32, page_size=8, num_pages=8)
+    prompt = np.arange(16, dtype=np.int32)  # 2 full pages
+    m.admit(0, prompt, max_new=8)  # 3 pages
+    m.register_prefix(0, prompt)
+    m.admit(1, 100 + np.arange(20, dtype=np.int32), max_new=12)  # 4 pages
+    before = m.stats()  # two cold admissions: 0 hits, 2 misses
+    assert (before["prefix_hits"], before["prefix_misses"]) == (0, 2)
+    longer = np.arange(24, dtype=np.int32)  # the 2 cached pages + 1 more
+    for _ in range(3):  # 4 pages needed, 0 free: refused, pages held
+        assert m.admit(2, longer, max_new=8) is None
+    assert m.stats() == before
+    m.free_slot(1)
+    res = m.admit(2, longer, max_new=8)
+    assert res is not None and res.matched == 16
+    after = m.stats()
+    assert after["prefix_hits"] == 2  # pages, as before
+    assert after["prefix_misses"] == 3  # its third page was not cached
+
+
 def _manager_admit_free_round_trip(seed, page_size, n_reqs):
     """Admissions and frees in random order: refcounts balance, the table
     maps exactly the held pages, and a drained manager leaves only
@@ -611,6 +633,55 @@ def test_paged_pallas_engine_matches_xla_bitwise():
                                    max_new_tokens=6, sampling=sp))
             outs[name] = {r.req_id: r.output for r in eng.run()}
         assert outs["pallas"] == outs["xla"], f"sampled={sampled}"
+
+
+@pytest.mark.parametrize("kind, scopes", [
+    ("paged_serve", {"layer_scan", "attention", "kv_write", "kv_layout",
+                     "mlp", "unembed", "sample"}),
+    ("paged_prefill_chunk", {"layer_scan", "attention", "kv_write",
+                             "kv_layout", "mlp", "unembed", "sample"})])
+def test_paged_steps_name_their_parts(kind, scopes):
+    """The compiled paged steps (Pallas path) carry the named scopes in
+    their ops' ``op_name`` metadata."""
+    import re
+
+    from repro.runtime.steps import compiled_step
+
+    model, params = tiny_lm()
+    model = LM(model.cfg, model.knobs.with_(use_pallas=True))
+    caches = model.init_cache_paged(9, 8)
+    table = jnp.zeros((2, 8), jnp.int32)
+    i32 = jnp.int32
+    args = ((params, caches, jnp.zeros((2, 1), i32), jnp.zeros(2, i32),
+             table) if kind == "paged_serve" else
+            (params, caches, jnp.zeros((1, 16), i32), i32(0), i32(0), table))
+    text = compiled_step(model, kind, page_size=8).lower(
+        *args).compile().as_text()
+    found = {part for path in re.findall(r'op_name="([^"]*)"', text)
+             for part in path.split("/")}
+    assert scopes <= found, scopes - found
+
+
+def test_engine_gives_the_hlo_of_the_steps_it_ran():
+    """``step_hlo_texts`` compiles again, from the shapes of its first
+    call, each step the engine ran: the program a call with the engine's
+    own arrays compiles."""
+    import re
+
+    model, params = tiny_lm()
+    eng = ServeEngine(model, params, ServeConfig(
+        batch_slots=2, max_len=32, cache="paged", page_size=8))
+    assert eng.step_hlo_texts() == []
+    eng.submit(Request(0, np.arange(1, 12, dtype=np.int32),
+                       max_new_tokens=3))
+    eng.run()
+    texts = eng.step_hlo_texts()
+    assert {re.search(r"^HloModule (\w+)", t, re.M).group(1)
+            for t in texts} == {"jit_prefill_chunk_step", "jit_serve_step"}
+    decode = eng._step.lower(
+        eng.params, eng.caches, jnp.asarray(eng.tokens),
+        jnp.asarray(eng.pos), jnp.asarray(eng.kv.page_table))
+    assert decode.compile().as_text() in texts
 
 
 @pytest.mark.slow

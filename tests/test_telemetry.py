@@ -29,9 +29,10 @@ from repro.runtime.fault import FaultEvent, ReplicaFaultInjector
 from repro.runtime.sampling import SamplingParams
 from repro.runtime.serve import Request, ServeConfig, ServeEngine
 from repro.runtime.steps import step_cache_stats
-from repro.runtime.telemetry import (NULL_TRACE, ROUTER_PID,
-                                     MetricsRegistry, NullTrace, Telemetry,
-                                     TraceRecorder, validate_chrome_trace)
+from repro.runtime.telemetry import (ENGINE_SPANS, ENGINE_TID, NULL_TRACE,
+                                     ROUTER_PID, MetricsRegistry, NullTrace,
+                                     Telemetry, TraceRecorder,
+                                     validate_chrome_trace)
 
 
 def _reqs(n=4, *, max_new=6, sampled=True, base_id=0):
@@ -196,6 +197,47 @@ def test_engine_spans_balanced_and_metrics(tmp_path):
     assert reg.value("engine_live_slots", replica="0") == 0
     path = tm.write_trace(str(tmp_path / "engine.json"))
     assert validate_chrome_trace(path)["balanced"]
+
+
+@pytest.mark.parametrize("mode, cache", [("continuous", "paged"),
+                                         ("wave", "dense")])
+def test_engine_tick_phases_on_the_engine_row(tmp_path, mode, cache):
+    """``Telemetry.span`` records each tick phase as a B/E pair on the
+    engine row, with its stats, and the trace stays balanced."""
+    tm = Telemetry(trace=True)
+    model, params = tiny_lm()
+    eng = ServeEngine(model, params, ServeConfig(
+        batch_slots=2, max_len=64, mode=mode, cache=cache, page_size=8),
+        telemetry=tm)
+    for r in _reqs(4, sampled=False):
+        eng.submit(r)
+    done = eng.run()
+    row = [e for e in tm.trace.events if e["tid"] == ENGINE_TID]
+    begins = [e for e in row if e["ph"] == "B"]
+    assert len(begins) == sum(e["ph"] == "E" for e in row)
+    want = set(ENGINE_SPANS) - ({"engine.prefill"} if mode == "wave"
+                                else set())
+    assert {e["name"] for e in begins} == want
+    assert sum(e["name"] == "engine.step" for e in begins) == \
+        tm.registry.value("engine_ticks_total", replica="0")
+    ends = [e.get("args", {}) for e in row if e["ph"] == "E"]
+    emitted = sum(a.get("emitted", 0) for a in ends)
+    first = len(done) if mode == "continuous" else 0  # from prefill
+    assert emitted + first == sum(len(r.output) for r in done)
+    assert sum(a.get("admitted", 0) for a in ends) == len(done)
+    if mode == "continuous":
+        prefills = [e["args"] for e in begins if e["name"] == "engine.prefill"]
+        assert sorted(a["tokens"] for a in prefills) == \
+            sorted(len(r.prompt) for r in done)
+    path = tm.write_trace(str(tmp_path / "phases.json"))
+    assert validate_chrome_trace(path)["balanced"]
+
+
+def test_span_without_a_recorder_is_an_annotation_only():
+    tm = Telemetry()
+    with tm.span("engine.step", pid=3) as sp:
+        sp.set(emitted=1)
+    assert tm.trace is NULL_TRACE and tm.trace.open_spans() == {}
 
 
 def test_preemption_spans_balanced():
