@@ -89,8 +89,8 @@ def paged_decode_attention(q, k_pages, v_pages, page_idx, pos, *, active=None,
     """Model layout: q (B,T,H,D); pools (P, page_size, KV, D); page_idx
     (B, max_pages) int32 -> (B,T,H,D).
 
-    Paged mirror of ``decode_attention``: the KV stream is gathered
-    through the page table by the kernel's scalar-prefetched index_map.
+    Paged mirror of ``decode_attention``: one program per slot copies
+    the pages it attends through the scalar-prefetched page table.
     Unmapped entries must be 0 (null page); ``pos``/``active`` follow the
     ragged contract.  ``k_scale``/``v_scale`` (P, page_size, KV, 1) f32
     select the quantized (int8/fp8 pool) path; ``num_splits > 1`` selects

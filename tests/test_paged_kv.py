@@ -13,7 +13,8 @@ from conftest import make_engine, tiny_lm
 
 from repro.configs import get_config
 from repro.kernels.paged_attention import paged_decode_attention_tpu
-from repro.kernels.ref import decode_attention_ref, paged_decode_attention_ref
+from repro.kernels.ref import (decode_attention_ref, paged_decode_attention_ref,
+                               paged_decode_attention_quant_ref)
 from repro.models import LM, RuntimeKnobs
 from repro.models.attention import (paged_cache_update,
                                     paged_decode_attention_xla)
@@ -114,6 +115,61 @@ def test_paged_xla_matches_ref():
         window=4)
     assert float(jnp.max(jnp.abs(out.swapaxes(1, 2) - ref))) < 1e-5
     assert float(jnp.max(jnp.abs(out[0]))) == 0.0  # inactive slot zeroed
+
+
+def _poison_unread_pages(pools, pt, pos, tq, page_size, window):
+    """NaN in every page the kernel must not read: a slot's pages before
+    its window or past its last query row, every page of an inactive
+    slot, and the pages no slot maps."""
+    read = set()
+    for row, p in zip(pt, pos):
+        if p < 0:
+            continue
+        first = max(0, p - window + 1) // page_size if window else 0
+        read |= set(row[first:(p + tq - 1) // page_size + 1].tolist())
+    unread = np.array(sorted(set(range(pools[0].shape[0])) - read))
+    return [x.at[unread].set(jnp.nan) for x in pools]
+
+
+@pytest.mark.parametrize("g", [1, 2])
+@pytest.mark.parametrize("tq", [1, 4])
+@pytest.mark.parametrize("window", [0, 8])
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+def test_paged_decode_reads_only_the_pages_a_slot_attends(g, tq, window,
+                                                          dtype):
+    """The per-slot kernel matches the oracle with NaN in every page past
+    a slot's ``pos`` (or before its window) and in every page of an
+    inactive slot, so nothing it must not read reaches the result.  Page
+    tables are shuffled; slots sit at -1, 0, on and just past a page
+    boundary, and at seven pages, which leave a last block of three under
+    the kernel's four-page (128-key) blocks at ``page_size`` 32."""
+    from repro.models.attention import quantize_kv
+
+    b, kv, d, ps, mp = 6, 2, 16, 32, 8
+    pos = np.array([-1, 0, 31, 32, 33, 204 - tq], np.int32)
+    kp, vp, pt = _paged_case(b, kv, kv * g, d, ps, mp)
+    q = arr(b, kv * g, tq, d)
+    if dtype == "int8":
+        (kq, ks), (vq, vs) = quantize_kv(kp, jnp.int8), quantize_kv(vp,
+                                                                    jnp.int8)
+        ref = paged_decode_attention_quant_ref(q, kq, vq, ks, vs, pt, pos,
+                                               window=window)
+        ks, vs = _poison_unread_pages([ks, vs], pt, pos, tq, ps, window)
+        kw = dict(k_scale=ks, v_scale=vs)
+        tol = 1e-3
+    else:
+        q, kq, vq = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
+        ref = paged_decode_attention_ref(q.astype(jnp.float32), kq, vq, pt,
+                                         pos, window=window)
+        kq, vq = _poison_unread_pages([kq, vq], pt, pos, tq, ps, window)
+        kw = {}
+        tol = 3e-2  # bf16 output, p rounded to bf16 for the PV matmul
+    out = paged_decode_attention_tpu(q, kq, vq, jnp.asarray(pt), pos,
+                                     window=window, interpret=True, **kw)
+    out = np.asarray(out.astype(jnp.float32))
+    assert np.isfinite(out).all()
+    assert np.abs(out - np.asarray(ref)).max() < tol
+    assert (out[0] == 0).all()  # inactive slot writes zeros
 
 
 def test_paged_cache_update_writes_mapped_page_and_null_for_inactive():

@@ -58,15 +58,17 @@ def _compile(fn, *args):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _kernel_args(name, sds):
+def _kernel_args(name, sds, b=B, n_pool=POOL):
     i32, bf16, f32 = jnp.int32, jnp.bfloat16, jnp.float32
-    pool = (POOL, PS, KV, D)
-    table, pos = sds((B, MAX_PAGES), i32), sds((B,), i32)
+    pool = (n_pool, PS, KV, D)
+    table, pos = sds((b, MAX_PAGES), i32), sds((b,), i32)
+    if name == "paged_decode_chat":  # the chat cell's engine and pool
+        return _kernel_args("paged_decode", sds, b=30, n_pool=3361)
     if name.startswith("paged_decode"):
         tq = 4 if name.endswith("tq4") else 1
         splits = 4 if "splitk" in name else 1
         dt = jnp.int8 if "int8" in name else bf16
-        args = [sds((B, tq, H, D), bf16), sds(pool, dt), sds(pool, dt),
+        args = [sds((b, tq, H, D), bf16), sds(pool, dt), sds(pool, dt),
                 table, pos]
         if dt == bf16:
             def fn(q, k, v, t, p):
@@ -79,7 +81,7 @@ def _kernel_args(name, sds):
             return ops.paged_decode_attention(q, k, v, t, p, k_scale=ks,
                                               v_scale=vs, num_splits=splits,
                                               interpret=False)
-        return fn, args + [sds((POOL, PS, KV, 1), f32)] * 2
+        return fn, args + [sds((n_pool, PS, KV, 1), f32)] * 2
     if name == "paged_prefill":
         def fn(q, k, v, t, slot, off):
             return ops.paged_prefill_attention(q, k, v, t, slot, off,
@@ -104,7 +106,7 @@ def _kernel_args(name, sds):
 @pytest.mark.parametrize("name", [
     "paged_decode", "paged_decode_tq4", "paged_decode_int8",
     "paged_decode_splitk", "paged_decode_int8_splitk", "paged_prefill",
-    "dense_decode", "flash"])
+    "dense_decode", "flash", "paged_decode_chat"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
