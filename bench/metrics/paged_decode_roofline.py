@@ -11,8 +11,11 @@ bandwidth; at these shapes bytes bind (about one FLOP per byte).  A
 kernel that reads pages twice or reads pages past ``pos`` does not raise
 the required bytes, so it lowers the share.
 
-The kernels are the single-pass ``_paged_decode_kernel`` and the split-K
-``_paged_splitk_partial_kernel`` with its combine kernel."""
+One kernel body, ``_paged_decode_kernel``, serves decode: one program per
+slot and split, grid ``(B, num_splits)``, each copying the pages its slot
+attends once for all KV heads.  Split-K runs the same body over page
+ranges and merges the partials in the dense ``_splitk_combine_kernel``.
+The device time is that of the ops ``KERNELS`` matches."""
 KERNELS = r"^paged_decode_attention|splitk"
 ITEMSIZE = 2  # bf16
 

@@ -1,27 +1,32 @@
-"""Plain float32 reference of the served decoder, independent of the program.
+"""Plain float32 reference of the served model, independent of the program.
 
 It imports nothing of ``repro`` and reads no array the program made: the
 weights are regenerated here, layer by layer, from the run's seed
 (``weights.py``).  Every matrix product runs at
 ``jax.lax.Precision.HIGHEST`` (float32 on the TPU, not one bf16 pass).
 
-The block follows the repo's dense decoder, which departs from the
-published models as the configuration files' ``assumed`` say: RMSNorm
-(scale only), RoPE with the split-half rotation, no biases, and for a
-non-gated MLP the tanh form of GELU.  The unembedding is a table of its
-own, or the embedding table where the configuration ties them.  Attention is
-causal GQA/MQA with scale ``head_dim ** -0.5`` and a float32 softmax.
+The model's equations are its block's (``blocks/<block>.py``, whose
+``Dims`` every function here takes): ``dims.embed(outer, tokens,
+positions)`` makes the hidden states, ``dims.layer(h, w, lowp)`` runs one
+layer and ``dims.head(outer, h)`` gives the logits, each on float32
+weights.  What is here serves every block: the bucketing, causal
+attention in blocks of query rows (``attention``), a row-blocked map for
+the MLP (``row_blocks``) and the float8 rounding of the control
+(``_fp8``, ``cached``).
 
 The sequences are run one at a time, each padded at its end to a bucket of
 ``BUCKET`` positions (padding past a causal sequence changes none of its
-rows), attention in blocks of ``Q_BLOCK`` query rows and the MLP in blocks
-of ``ROW_BLOCK`` rows, so a 13-layer, 6,144-wide model fits beside nothing
-else on one chip.
+rows; the padding's positions run on past the sequence, so a block with a
+table of positions clips them), attention in blocks of ``Q_BLOCK`` query
+rows and the MLP in blocks of ``ROW_BLOCK`` rows, so a 13-layer,
+6,144-wide model fits beside nothing else on one chip.
 
-``lowp=True`` is the correctness check's control: the same forward with
-every weight matrix and every cached key and value rounded to float8
-(e4m3, one absmax scale per output column or per token and head), the
-precision below the bf16 the configurations serve in.
+``lowp=True`` is the correctness check's control: the same forward in
+float8 e4m3, the precision below the bf16 the configurations serve in.
+Every weight that ``dims.fp8_axes()`` names is rounded here, with one
+absmax scale along the axes it gives (those a matrix reduces over, so one
+scale per output column), and the block passes each key and value it
+caches through ``cached`` (one scale per token and head).
 """
 from __future__ import annotations
 
@@ -48,20 +53,19 @@ def _fp8(x, axis):
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def rmsnorm(x, scale, eps):
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * scale
+def cached(x, lowp: bool):
+    """Keys or values (S, KV, hd) as the control's cache holds them."""
+    return _fp8(x, -1) if lowp else x
 
 
-def rope(x, theta):
-    """x (S, H, hd) at positions 0..S-1, split-half rotation."""
-    s, _, hd = x.shape
-    freqs = jnp.asarray(1.0 / (theta ** (np.arange(0, hd, 2) / hd)),
-                        jnp.float32)
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * freqs
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+def _fp8_leaves(tree, axes: dict):
+    """``tree`` with each leaf whose path ``axes`` names rounded to fp8
+    along the axes it gives."""
+    def leaf(path, a):
+        name = "/".join(k.key for k in path)
+        return _fp8(a, axes[name]) if name in axes else a
+
+    return jax.tree_util.tree_map_with_path(leaf, tree)
 
 
 def attention(q, k, v):
@@ -83,75 +87,43 @@ def attention(q, k, v):
     return out.reshape(s, h, hd)
 
 
-def mlp(x, w, gated):
-    def rows(xb):
-        if gated:
-            hid = (jax.nn.silu(jnp.dot(xb, w["w_gate"], precision=HI))
-                   * jnp.dot(xb, w["w_up"], precision=HI))
-        else:
-            hid = jax.nn.gelu(jnp.dot(xb, w["w_up"], precision=HI),
-                              approximate=True)
-        return jnp.dot(hid, w["w_down"], precision=HI)
-
+def row_blocks(fn, x):
+    """``fn`` over ``x`` (S, d) in blocks of ``ROW_BLOCK`` rows."""
     s, d = x.shape
-    return jax.lax.map(rows, x.reshape(s // ROW_BLOCK, ROW_BLOCK, d)
-                       ).reshape(s, d)
+    out = jax.lax.map(fn, x.reshape(s // ROW_BLOCK, ROW_BLOCK, d))
+    return out.reshape(s, out.shape[-1])
 
 
-def _lowp_weights(w):
-    """Every matrix rounded to fp8 with one scale per output column."""
-    a = w["attn"]
-    return dict(w, attn={"wq": _fp8(a["wq"], 0), "wk": _fp8(a["wk"], 0),
-                         "wv": _fp8(a["wv"], 0), "wo": _fp8(a["wo"], (0, 1))},
-                mlp={k: _fp8(m, 0) for k, m in w["mlp"].items()})
+def _f32(tree):
+    return jax.tree.map(lambda a: a.astype(jnp.float32), tree)
 
 
-@functools.lru_cache(maxsize=None)
-def _layer_fn(dims: W.Dims, lowp: bool):
-    def layer(h, w):
-        w = jax.tree.map(lambda a: a.astype(jnp.float32), w)
-        if lowp:
-            w = _lowp_weights(w)
-        a = w["attn"]
-        x = rmsnorm(h, w["ln1"]["scale"], dims.eps)
-        q = rope(jnp.einsum("sd,dhk->shk", x, a["wq"], precision=HI),
-                 dims.rope_theta)
-        k = rope(jnp.einsum("sd,dhk->shk", x, a["wk"], precision=HI),
-                 dims.rope_theta)
-        v = jnp.einsum("sd,dhk->shk", x, a["wv"], precision=HI)
-        if lowp:
-            k, v = _fp8(k, -1), _fp8(v, -1)
-        h = h + jnp.einsum("shk,hkd->sd", attention(q, k, v), a["wo"],
-                           precision=HI)
-        return h + mlp(rmsnorm(h, w["ln2"]["scale"], dims.eps), w["mlp"],
-                       dims.gated)
-
-    return jax.jit(layer)
+def _weights(tree, dims, lowp: bool):
+    tree = _f32(tree)
+    return _fp8_leaves(tree, dims.fp8_axes()) if lowp else tree
 
 
 @functools.lru_cache(maxsize=None)
-def _head_fn(dims: W.Dims, lowp: bool):
-    def logits(h, table, scale):
-        table = table.astype(jnp.float32)
-        if lowp:
-            table = _fp8(table, 1)
-        x = rmsnorm(h, scale.astype(jnp.float32), dims.eps)
-        return jnp.dot(x, table.T, precision=HI)
-
-    return jax.jit(logits)
+def _layer_fn(dims, lowp: bool):
+    return jax.jit(lambda h, w: dims.layer(h, _weights(w, dims, lowp), lowp))
 
 
-def logits(dims: W.Dims, seed: int, seqs, rows, *, lowp=False):
+@functools.lru_cache(maxsize=None)
+def _head_fn(dims, lowp: bool):
+    return jax.jit(lambda h, outer: dims.head(_weights(outer, dims, lowp), h))
+
+
+def logits(dims, seed: int, seqs, rows, *, lowp=False):
     """Logits at ``rows[i]`` of sequence ``seqs[i]`` (token ids), one
     (len(rows[i]), vocab) float32 array per sequence, as numpy."""
-    table, final, unembed = W.head(dims, seed)
+    outer = W.head(dims, seed)
     hs = []
     for s in seqs:
         pad = -(-len(s) // BUCKET) * BUCKET
         tok = np.zeros(pad, np.int32)
         tok[:len(s)] = s
-        hs.append(jnp.take(table, jnp.asarray(tok), axis=0)
-                  .astype(jnp.float32))
+        hs.append(dims.embed(outer, jnp.asarray(tok),
+                             jnp.arange(pad, dtype=jnp.int32)))
     layer = _layer_fn(dims, lowp)
     for i in range(dims.layers):
         w = W.layer(dims, seed, i)
@@ -163,8 +135,7 @@ def logits(dims: W.Dims, seed: int, seqs, rows, *, lowp=False):
         # rows padded to a whole block, so one compiled head serves all
         idx = np.full(-(-len(r) // HEAD_ROWS) * HEAD_ROWS, r[-1], np.int32)
         idx[:len(r)] = r
-        out.append(np.asarray(head(h[jnp.asarray(idx)], unembed,
-                                   final))[:len(r)])
+        out.append(np.asarray(head(h[jnp.asarray(idx)], outer))[:len(r)])
     return out
 
 

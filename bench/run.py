@@ -4,16 +4,20 @@
         --trace 0
 
 Everything a cell needs is found by name: its entry in ``BENCHMARK.json``,
-``bench/configs/<config>.json`` (model, engine shape), ``bench/traffic/
+``bench/configs/<config>.json`` (model, engine shape) and ``bench/blocks/
+<block>.py`` (the model block its ``block`` names: the weights' layout, the
+reference's forward and the program check), ``bench/traffic/
 <traffic>.json`` (the mix) and ``bench/traffic/<kind>.py`` (the generator
 its ``kind`` names), ``bench/cells/<workload>.json`` (the limits of
 the correctness check), ``bench/e2e/<metric>.py`` and ``bench/metrics/
 <metric>.py`` (one reader per metric) and ``bench/peaks.json`` (the chip's
 peaks by ``device_kind``).
 
-A run: check for a TPU (exit 1 before any work if there is none, or too
-few chips); draw the weights on the device from ``--seed``; size the KV
-pool with the program's ``fit_device_pool``; build ``ServeEngine``
+A run: check that the configuration's block exists and that there is a
+TPU (exit 1 before any work if not, or with too few chips); check the
+program's architecture against the block (exit 1 where it departs); draw
+the weights on the device from ``--seed``; size the KV pool with the
+program's ``fit_device_pool``; build ``ServeEngine``
 (continuous, paged, prefix cache on, fcfs, the launcher's TPU knobs);
 run every step shape the cell can reach once; then serve the mix's
 warm-up and the measured window of ``--seconds``.  ``setup_s`` runs from
@@ -34,6 +38,7 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -65,8 +70,33 @@ def load_reader(kind: str, name: str):
     spec = importlib.util.spec_from_file_location(
         f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod  # dataclasses look their module up
     spec.loader.exec_module(mod)
     return mod
+
+
+def require_block(config: dict) -> str:
+    """The block ``config`` names; exits 1 where ``bench/blocks`` has no
+    such file."""
+    name = config.get("block")
+    known = sorted(p.stem for p in (BENCH / "blocks").glob("*.py"))
+    if name not in known:
+        raise SystemExit(f"bench: configuration {config.get('name')!r} "
+                         f"names block {name!r}; known blocks: {known}")
+    return name
+
+
+@functools.cache
+def load_block(name: str):
+    """``bench/blocks/<name>.py``, loaded once a process so that its
+    ``Dims`` keys the compiled weights and reference programs."""
+    return load_reader("blocks", name)
+
+
+def block_dims(config: dict):
+    """The configuration's sizes as its block's ``Dims``."""
+    return load_block(require_block(config)).Dims.from_config(
+        config["model"])
 
 
 @dataclasses.dataclass
@@ -89,6 +119,8 @@ def find_cell(bench: dict, name: str) -> Cell:
         raise SystemExit(f"unknown workload {name!r}; known: "
                          f"{sorted(by_name)}")
     w = by_name[name]
+    config = load_json(BENCH / "configs" / f"{w['config']}.json")
+    require_block(config)
 
     def applies(m):
         return name in m.get("workloads", [name])
@@ -98,8 +130,7 @@ def find_cell(bench: dict, name: str) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in names)]
-    return Cell(workload=w,
-                config=load_json(BENCH / "configs" / f"{w['config']}.json"),
+    return Cell(workload=w, config=config,
                 mix=load_json(BENCH / "traffic" / f"{w['traffic']}.json"),
                 limits=load_json(BENCH / "cells" / f"{name}.json")["limits"],
                 e2e=e2e, per_layer=per_layer)
@@ -164,29 +195,35 @@ class CompileCounter:
 
 
 # ---------------------------------------------------------------- program
+_ABSENT = object()
+
+
+def check_program(dims, cfg) -> None:
+    """Exit naming each field where the program's ``ArchConfig`` departs
+    from what the block and its configuration file state (a field the
+    program lacks departs too)."""
+    diff = [f"{k}: program {getattr(cfg, k, '<absent>')!r}, file {v!r}"
+            for k, v in dims.arch().items()
+            if getattr(cfg, k, _ABSENT) != v]
+    if diff:
+        raise SystemExit(f"bench: the program's {cfg.name} departs from "
+                         f"the configuration file: {'; '.join(diff)}")
+
+
 def build_model(cell: Cell, backend: str):
     """(the program's model, dims) for the cell's configuration, with the
     knobs the launcher serves ``backend`` with; exits where the program's
     architecture departs from what the configuration file states."""
     import jax
 
-    import weights as W
     from repro.configs import get_config
     from repro.launch.serve import serving_knobs
     from repro.models import LM
 
     prog = cell.config["program"]
     cfg = dataclasses.replace(get_config(prog["arch"]), **prog["overrides"])
-    dims = W.Dims.from_config(cell.config["model"])
-    got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
-           cfg.head_dim, cfg.d_ff, cfg.vocab_size, cfg.gated_mlp,
-           cfg.rope_theta, cfg.tie_embeddings, cfg.qkv_bias, cfg.window)
-    want = (dims.layers, dims.d_model, dims.heads, dims.kv_heads,
-            dims.head_dim, dims.d_ff, dims.vocab, dims.gated,
-            dims.rope_theta, dims.tied, False, 0)
-    if got != want:
-        raise SystemExit(f"bench: the program's {cfg.name} is {got}, the "
-                         f"configuration file states {want}")
+    dims = block_dims(cell.config)
+    check_program(dims, cfg)
     model = LM(cfg, serving_knobs(backend, sharded=False))
     if jax.numpy.dtype(model.knobs.param_dtype) != jax.numpy.dtype(dims.dtype):
         raise SystemExit(f"bench: the program serves "

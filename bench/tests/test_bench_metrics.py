@@ -12,13 +12,12 @@ BENCH = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 
 import devtrace as TR  # noqa: E402
-import weights as W  # noqa: E402
 from loadgen import Tick  # noqa: E402
 
 RUN = None
 
 
-def _load(name):
+def _run():
     global RUN
     if RUN is None:
         import importlib.util
@@ -27,12 +26,16 @@ def _load(name):
         RUN = importlib.util.module_from_spec(spec)
         sys.modules[spec.name] = RUN
         spec.loader.exec_module(RUN)
-    return RUN.load_reader("metrics", name)
+    return RUN
+
+
+def _load(name):
+    return _run().load_reader("metrics", name)
 
 
 # 2 layers, 4 query heads over 2 KV heads of 16, d_model 64, d_ff 128
-DIMS = W.Dims.from_config(json.loads(
-    (BENCH / "tests" / "data" / "tiny.json").read_text())["model"])
+DIMS = _run().block_dims(json.loads(
+    (BENCH / "tests" / "data" / "tiny.json").read_text()))
 PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9}
 
 

@@ -15,6 +15,7 @@ sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(BENCH.parent / "src"))
 
 import reference as R  # noqa: E402
+import run  # noqa: E402
 import weights as W  # noqa: E402
 
 TINY = json.loads((BENCH / "tests" / "data" / "tiny.json").read_text())
@@ -24,7 +25,7 @@ SEED = 2**35 + 17
 
 
 def _dims(**over):
-    return dataclasses.replace(W.Dims.from_config(TINY["model"]), **over)
+    return dataclasses.replace(run.block_dims(TINY), **over)
 
 
 def _program(dims):
@@ -48,15 +49,16 @@ def test_layers_regenerate_bit_for_bit():
         for a, b in zip(jax.tree.leaves(stacked["blocks"]["stack"]),
                         jax.tree.leaves(one)):
             assert np.array_equal(np.asarray(a[i]), np.asarray(b))
-    table, scale, unembed = W.head(dims, SEED, jnp.float32)
-    assert np.array_equal(table, stacked["embed"]["table"])
-    assert np.array_equal(scale, stacked["final_norm"]["scale"])
-    assert unembed is table and "head" not in stacked["embed"]
+    outer = W.head(dims, SEED, jnp.float32)
+    assert np.array_equal(outer["embed"]["table"], stacked["embed"]["table"])
+    assert np.array_equal(outer["final_norm"]["scale"],
+                          stacked["final_norm"]["scale"])
+    assert "head" not in outer["embed"] and "head" not in stacked["embed"]
     untied = _dims(tied=False)
-    table, _, unembed = W.head(untied, SEED, jnp.float32)
-    assert np.array_equal(unembed, W.params(untied, SEED, jnp.float32)
-                          ["embed"]["head"])
-    assert not np.array_equal(unembed, table)
+    outer = W.head(untied, SEED, jnp.float32)
+    assert np.array_equal(outer["embed"]["head"], W.params(
+        untied, SEED, jnp.float32)["embed"]["head"])
+    assert not np.array_equal(outer["embed"]["head"], outer["embed"]["table"])
     other = W.params(dims, SEED + 2**32, jnp.float32)
     assert not np.array_equal(other["embed"]["table"],
                               stacked["embed"]["table"])
