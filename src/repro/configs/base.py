@@ -5,6 +5,19 @@ assigned input shapes are global (same for every arch) and are expressed as
 ``ShapeSpec``.  ``input_specs`` builds jax.ShapeDtypeStruct stand-ins for the
 dry-run (no device allocation).
 
+The block's norm, positions and biases are fields too, and their defaults
+are the llama-style block every config but granite-20b runs:
+
+* ``norm`` — ``"rmsnorm"`` (a scale; the default) or ``"layernorm"``
+  (mean removed, then a scale and a bias), each with ``norm_eps`` (1e-6 by
+  default), for both sublayer norms and the final norm.
+* ``position_embeddings`` — ``"rope"`` (the default; ``rope_theta``) or
+  ``"learned"``: a table of ``max_positions`` rows added to the token
+  embedding at each token's absolute position, and no rotation.
+* ``qkv_bias`` — biases on q, k and v; ``proj_bias`` — biases on the
+  attention output projection and on the MLP's matrices.  Both default to
+  False.
+
 Pure-python module: importing it must never touch jax device state.
 """
 from __future__ import annotations
@@ -85,7 +98,14 @@ class ArchConfig:
     local_window: int = 0  # window of the *local* layers (gemma3)
     local_global_period: int = 0  # gemma3: every Nth layer is global
     qkv_bias: bool = False
+    # a bias on the attention output projection and on both MLP matrices
+    proj_bias: bool = False
     gated_mlp: bool = True  # SwiGLU; False -> 2-matrix GELU MLP (granite)
+    # --- norms and positions ---
+    norm: str = "rmsnorm"  # "rmsnorm" (scale) | "layernorm" (scale, bias)
+    norm_eps: float = 1e-6
+    position_embeddings: str = "rope"  # "rope" | "learned" (absolute table)
+    max_positions: int = 0  # rows of the learned position table
     rope_theta: float = 10_000.0
     # --- mixture of experts ---
     moe: Optional[MoEConfig] = None
@@ -138,6 +158,9 @@ class ArchConfig:
         n = 0
         # embeddings (+ untied head)
         n += self.vocab_size * dm * (1 if self.tie_embeddings else 2)
+        if self.position_embeddings == "learned":
+            n += self.max_positions * dm
+        norm_leaves = 2 if self.norm == "layernorm" else 1  # scale (+ bias)
         for kind in self.layer_kinds():
             if kind == "ssm":
                 assert self.ssm is not None
@@ -154,6 +177,10 @@ class ArchConfig:
                 n += dm * self.num_heads * hd  # wq
                 n += 2 * dm * self.num_kv_heads * hd  # wk, wv
                 n += self.num_heads * hd * dm  # wo
+                if self.qkv_bias:
+                    n += (self.num_heads + 2 * self.num_kv_heads) * hd
+                if self.proj_bias:
+                    n += dm  # bo
             # ffn sublayer
             if kind == "moe":
                 assert self.moe is not None
@@ -162,13 +189,15 @@ class ArchConfig:
                 n += e * 3 * dm * self.moe.d_ff
             elif kind != "ssm":
                 n += (3 if self.gated_mlp else 2) * dm * self.d_ff
-            n += 2 * dm  # two rmsnorm scales
+                if self.proj_bias:
+                    n += self.d_ff + dm  # b_up, b_down
+            n += 2 * dm * norm_leaves  # two norms
         if self.shared_attn_period:
             # one shared transformer block (zamba-style), weights reused
             hd = self.head_dim
             n += dm * self.num_heads * hd + 2 * dm * self.num_kv_heads * hd
             n += self.num_heads * hd * dm + 3 * dm * self.d_ff + 2 * dm
-        n += dm  # final norm
+        n += dm * norm_leaves  # final norm
         return n
 
     def experts_counted(self, active_only: bool) -> int:

@@ -24,7 +24,7 @@ from .layers import _init, apply_rope
 
 # ------------------------------------------------------------------ params
 def attention_init(key, *, d_model, num_heads, num_kv_heads, head_dim, qkv_bias,
-                   dtype=jnp.float32):
+                   proj_bias=False, dtype=jnp.float32):
     ks = jax.random.split(key, 4)
     p = {
         "wq": _init(ks[0], (d_model, num_heads, head_dim), dtype=dtype),
@@ -36,11 +36,15 @@ def attention_init(key, *, d_model, num_heads, num_kv_heads, head_dim, qkv_bias,
         p["bq"] = jnp.zeros((num_heads, head_dim), dtype=dtype)
         p["bk"] = jnp.zeros((num_kv_heads, head_dim), dtype=dtype)
         p["bv"] = jnp.zeros((num_kv_heads, head_dim), dtype=dtype)
+    if proj_bias:
+        p["bo"] = jnp.zeros((d_model,), dtype=dtype)
     return p
 
 
 def qkv_project(params, x, positions, rope_theta):
-    """x (B,S,dm) -> q (B,S,H,hd), k,v (B,S,KV,hd) with RoPE applied."""
+    """x (B,S,dm) -> q (B,S,H,hd), k,v (B,S,KV,hd) with RoPE applied
+    (none where ``rope_theta`` is None: positions were learned and added
+    to the embedding)."""
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, params["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, params["wv"])
@@ -48,13 +52,15 @@ def qkv_project(params, x, positions, rope_theta):
         q = q + params["bq"]
         k = k + params["bk"]
         v = v + params["bv"]
-    q = apply_rope(q, positions, rope_theta)
-    k = apply_rope(k, positions, rope_theta)
+    if rope_theta is not None:
+        q = apply_rope(q, positions, rope_theta)
+        k = apply_rope(k, positions, rope_theta)
     return q, k, v
 
 
 def attn_output(params, ctx):
-    return jnp.einsum("bshk,hkd->bsd", ctx, params["wo"])
+    out = jnp.einsum("bshk,hkd->bsd", ctx, params["wo"])
+    return out + params["bo"] if "bo" in params else out
 
 
 # ------------------------------------------------------- grouped attention

@@ -1,4 +1,5 @@
-"""Shared layers: RMSNorm, RoPE, gated MLP, embeddings, chunked CE loss.
+"""Shared layers: RMSNorm and LayerNorm, RoPE, MLP, embeddings, chunked CE
+loss.
 
 All functions are pure; parameters are plain dicts of jnp arrays.  Weight
 init returns fp32 or the requested param dtype; compute happens in the dtype
@@ -28,6 +29,35 @@ def rmsnorm(params, x, eps: float = 1e-6):
     return (out * params["scale"].astype(jnp.float32)).astype(dt)
 
 
+# -------------------------------------------------------------- layernorm
+def layernorm_init(d: int, dtype=jnp.float32):
+    return {"scale": jnp.ones((d,), dtype=dtype),
+            "bias": jnp.zeros((d,), dtype=dtype)}
+
+
+def layernorm(params, x, eps: float):
+    dt = x.dtype
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    xc = xf - mu
+    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
+    out = xc * jax.lax.rsqrt(var + eps) * params["scale"].astype(jnp.float32)
+    return (out + params["bias"].astype(jnp.float32)).astype(dt)
+
+
+def norm_init(cfg, dtype=jnp.float32):
+    """The params of one of ``cfg``'s norms (``cfg.norm``)."""
+    init = layernorm_init if cfg.norm == "layernorm" else rmsnorm_init
+    return init(cfg.d_model, dtype)
+
+
+def norm(params, x, cfg):
+    """``cfg``'s norm (``cfg.norm`` at ``cfg.norm_eps``) of ``x``."""
+    if cfg.norm == "layernorm":
+        return layernorm(params, x, cfg.norm_eps)
+    return rmsnorm(params, x, cfg.norm_eps)
+
+
 # ------------------------------------------------------------------- rope
 def rope_frequencies(head_dim: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
@@ -46,7 +76,8 @@ def apply_rope(x, positions, theta: float):
 
 
 # -------------------------------------------------------------------- mlp
-def mlp_init(key, d_model: int, d_ff: int, gated: bool, dtype=jnp.float32):
+def mlp_init(key, d_model: int, d_ff: int, gated: bool, dtype=jnp.float32,
+             bias: bool = False):
     ks = jax.random.split(key, 3)
     p = {"w_down": _init(ks[2], (d_ff, d_model), dtype=dtype)}
     if gated:
@@ -54,6 +85,9 @@ def mlp_init(key, d_model: int, d_ff: int, gated: bool, dtype=jnp.float32):
         p["w_up"] = _init(ks[1], (d_model, d_ff), dtype=dtype)
     else:
         p["w_up"] = _init(ks[1], (d_model, d_ff), dtype=dtype)
+    if bias:
+        p["b_up"] = jnp.zeros((d_ff,), dtype=dtype)
+        p["b_down"] = jnp.zeros((d_model,), dtype=dtype)
     return p
 
 
@@ -66,14 +100,20 @@ def mlp(params, x, gated: bool, shard_fn=lambda name, v: v):
     sits on the dot outputs, BEFORE the activation: gathering after it
     lets the partitioner compute the activation on the local shard,
     whose fused lowering differs from the full-width one by ~1 ulp
-    (measured on CPU; see tests/test_sharded_serve.py)."""
+    (measured on CPU; see tests/test_sharded_serve.py).  ``b_up`` and
+    ``b_down``, where ``params`` has them, are added after each product."""
     if gated:
         g = shard_fn("mlp_up", x @ params["w_gate"])
-        u = shard_fn("mlp_up", x @ params["w_up"])
+        u = shard_fn("mlp_up", _biased(x @ params["w_up"], params, "b_up"))
         h = jax.nn.silu(g) * u
     else:
-        h = jax.nn.gelu(shard_fn("mlp_up", x @ params["w_up"]))
-    return h @ params["w_down"]
+        h = jax.nn.gelu(shard_fn("mlp_up",
+                                 _biased(x @ params["w_up"], params, "b_up")))
+    return _biased(h @ params["w_down"], params, "b_down")
+
+
+def _biased(y, params, name: str):
+    return y + params[name] if name in params else y
 
 
 # ------------------------------------------------------------- embeddings
@@ -87,6 +127,18 @@ def embedding_init(key, vocab: int, d_model: int, tied: bool, dtype=jnp.float32)
 
 def embed(params, tokens):
     return jnp.take(params["table"], tokens, axis=0)
+
+
+def position_embedding_init(key, max_positions: int, d_model: int,
+                            dtype=jnp.float32):
+    return {"table": _init(key, (max_positions, d_model), dtype=dtype)}
+
+
+def embed_positions(params, positions):
+    """Rows of the learned position table at ``positions``; a position past
+    the table (a draft's padding, an idle slot's -1) reads its nearest
+    row."""
+    return jnp.take(params["table"], positions, axis=0, mode="clip")
 
 
 def unembed(params, x):

@@ -11,7 +11,8 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from .layers import chunked_ce_loss, embed, embedding_init, rmsnorm, rmsnorm_init, unembed
+from .layers import (chunked_ce_loss, embed, embed_positions, embedding_init,
+                     norm, norm_init, position_embedding_init, unembed)
 from .transformer import (apply_blocks, apply_blocks_decode,
                           apply_blocks_prefill_chunk, cache_batch_axes,
                           copy_cache_in, copy_cache_out, copy_cache_pages,
@@ -63,34 +64,50 @@ class LM:
     def init(self, key) -> dict:
         cfg, dt = self.cfg, self.knobs.param_dtype
         k1, k2 = jax.random.split(key)
-        return {
+        params = {
             "embed": embedding_init(k1, cfg.vocab_size, cfg.d_model,
                                     cfg.tie_embeddings, dt),
             "blocks": init_blocks(k2, cfg, dt),
-            "final_norm": rmsnorm_init(cfg.d_model, dt),
+            "final_norm": norm_init(cfg, dt),
         }
+        if cfg.position_embeddings == "learned":
+            params["pos_embed"] = position_embedding_init(
+                jax.random.fold_in(key, 2), cfg.max_positions, cfg.d_model, dt)
+        return params
 
     def param_specs(self):
         """Abstract params (no allocation) for the dry-run."""
         return jax.eval_shape(self.init, jax.random.PRNGKey(0))
 
     # ------------------------------------------------------------ forward
-    def _embed_inputs(self, params, batch):
-        cfg = self.cfg
-        if cfg.input_mode == "embeddings":
-            x = batch["embeds"].astype(self.knobs.compute_dtype)
-        else:
-            x = embed(params["embed"], batch["tokens"])
-        return x.astype(self.knobs.compute_dtype)
+    def _embed(self, params, tokens, start, embeds=None):
+        """Hidden states of ``tokens`` (B, T) (or of ``embeds``, the
+        embeddings input mode) at absolute positions ``start + t``;
+        ``start`` is a scalar or a (B,) vector of per-slot positions.
+        Where the config learns its positions, the table's rows at those
+        positions are added (summed in float32).  Under the device
+        program's ``embed`` scope."""
+        dt = self.knobs.compute_dtype
+        with jax.named_scope("embed"):
+            x = embed(params["embed"], tokens) if embeds is None else embeds
+            if self.cfg.position_embeddings != "learned":
+                return x.astype(dt)
+            start = jnp.asarray(start, jnp.int32)
+            positions = ((start.reshape(-1, 1) if start.ndim else start)
+                         + jnp.arange(tokens.shape[1]))
+            pos = embed_positions(params["pos_embed"], positions)
+            return (x.astype(jnp.float32) + pos.astype(jnp.float32)).astype(dt)
 
     def hidden(self, params, batch, mode: str):
-        x = self._embed_inputs(params, batch)
+        embeds = (batch["embeds"].astype(self.knobs.compute_dtype)
+                  if self.cfg.input_mode == "embeddings" else None)
+        x = self._embed(params, batch["tokens"], 0, embeds)
         x = self.knobs.shard_fn("hidden", x)
         b, s = x.shape[:2]
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
         x, aux, caches = apply_blocks(params["blocks"], x, positions,
                                       cfg=self.cfg, knobs=self.knobs, mode=mode)
-        x = rmsnorm(params["final_norm"], x)
+        x = norm(params["final_norm"], x, self.cfg)
         return x, aux, caches
 
     # --------------------------------------------------------------- loss
@@ -127,7 +144,8 @@ class LM:
         """Final norm and unembedding of the cached (serving) paths,
         under the device program's ``unembed`` scope."""
         with jax.named_scope("unembed"):
-            return unembed(params["embed"], rmsnorm(params["final_norm"], x))
+            return unembed(params["embed"],
+                           norm(params["final_norm"], x, self.cfg))
 
     def decode_step(self, params, caches, tokens, pos):
         """tokens (B,1) int32 -> (logits (B,V), new caches).
@@ -136,7 +154,7 @@ class LM:
         per-slot positions (ragged continuous batching); slots parked at
         pos = -1 are inactive and produce don't-care logits.
         """
-        x = embed(params["embed"], tokens).astype(self.knobs.compute_dtype)
+        x = self._embed(params, tokens, pos)
         x, new_caches = apply_blocks_decode(params["blocks"], x, caches, pos,
                                             cfg=self.cfg, knobs=self.knobs)
         logits = self._unembed(params, x)[:, 0, :]
@@ -150,7 +168,7 @@ class LM:
         (chunk logits (C,V) fp32, new caches).  The engine reads the logits
         row of the last real prompt token to seed decode.
         """
-        x = embed(params["embed"], tokens).astype(self.knobs.compute_dtype)
+        x = self._embed(params, tokens, offset)
         x, new_caches = apply_blocks_prefill_chunk(
             params["blocks"], x, caches, slot, offset, cfg=self.cfg,
             knobs=self.knobs)
@@ -179,7 +197,7 @@ class LM:
         accepted position and later writes overwrite the stale K/V,
         which the position mask keeps unattended until then.
         """
-        x = embed(params["embed"], tokens).astype(self.knobs.compute_dtype)
+        x = self._embed(params, tokens, pos)
         x, new_caches = apply_blocks_decode(params["blocks"], x, caches, pos,
                                             cfg=self.cfg, knobs=self.knobs)
         logits = self._unembed(params, x)
@@ -191,7 +209,7 @@ class LM:
         pages the slot's page-table row maps (positions past the mapped
         span write the null page — see
         ``attention.paged_cache_update_multi``)."""
-        x = embed(params["embed"], tokens).astype(self.knobs.compute_dtype)
+        x = self._embed(params, tokens, pos)
         x, new_caches = apply_blocks_decode(params["blocks"], x, caches, pos,
                                             cfg=self.cfg, knobs=self.knobs,
                                             paged=(page_idx, page_size))
@@ -207,7 +225,7 @@ class LM:
         """Paged ``decode_step``: caches are global page pools and slot
         ``b``'s KV prefix lives in pages ``page_idx[b]`` (0 = null page).
         ``page_size`` is static per engine."""
-        x = embed(params["embed"], tokens).astype(self.knobs.compute_dtype)
+        x = self._embed(params, tokens, pos)
         x, new_caches = apply_blocks_decode(params["blocks"], x, caches, pos,
                                             cfg=self.cfg, knobs=self.knobs,
                                             paged=(page_idx, page_size))
@@ -219,7 +237,7 @@ class LM:
         """Paged ``prefill_chunk_step``: the chunk (C a multiple of
         ``page_size``, ``offset`` page-aligned) writes the physical pages
         the slot's page-table row maps."""
-        x = embed(params["embed"], tokens).astype(self.knobs.compute_dtype)
+        x = self._embed(params, tokens, offset)
         x, new_caches = apply_blocks_prefill_chunk(
             params["blocks"], x, caches, slot, offset, cfg=self.cfg,
             knobs=self.knobs, paged=(page_idx, page_size))
@@ -236,7 +254,7 @@ class LM:
         (first chunk of a prefix-cache hit) rebuilds the view from the
         page table once.  Returns (logits, new caches, new buf)."""
         merged = zip_prefill_buf(caches, buf)
-        x = embed(params["embed"], tokens).astype(self.knobs.compute_dtype)
+        x = self._embed(params, tokens, offset)
         x, new_merged = apply_blocks_prefill_chunk(
             params["blocks"], x, merged, slot, offset, cfg=self.cfg,
             knobs=self.knobs, paged=(page_idx, page_size), gather=gather)

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from . import attention as attn
-from .layers import mlp, mlp_init, rmsnorm, rmsnorm_init
+from .layers import mlp, mlp_init, norm, norm_init, rmsnorm, rmsnorm_init
 from .moe import moe_ffn, moe_init
 from .ssm import ssm_decode_step, ssm_forward, ssm_init, ssm_init_cache
 
@@ -75,18 +75,24 @@ def build_plan(cfg) -> Plan:
 def _init_attn_block(key, cfg, dtype, ffn: str):
     ks = jax.random.split(key, 4)
     p = {
-        "ln1": rmsnorm_init(cfg.d_model, dtype),
+        "ln1": norm_init(cfg, dtype),
         "attn": attn.attention_init(
             ks[0], d_model=cfg.d_model, num_heads=cfg.num_heads,
             num_kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
-            qkv_bias=cfg.qkv_bias, dtype=dtype),
-        "ln2": rmsnorm_init(cfg.d_model, dtype),
+            qkv_bias=cfg.qkv_bias, proj_bias=cfg.proj_bias, dtype=dtype),
+        "ln2": norm_init(cfg, dtype),
     }
     if ffn == "moe":
         p["moe"] = moe_init(ks[1], cfg.d_model, cfg.moe, dtype)
     elif ffn == "mlp":
-        p["mlp"] = mlp_init(ks[1], cfg.d_model, cfg.d_ff, cfg.gated_mlp, dtype)
+        p["mlp"] = mlp_init(ks[1], cfg.d_model, cfg.d_ff, cfg.gated_mlp, dtype,
+                            bias=cfg.proj_bias)
     return p
+
+
+def _rope_theta(cfg):
+    """RoPE's theta, or None where positions are a learned table."""
+    return cfg.rope_theta if cfg.position_embeddings == "rope" else None
 
 
 def _init_ssm_block(key, cfg, dtype):
@@ -141,8 +147,8 @@ def _acc_aux(aux, new):
 
 def _apply_attn_block(p, x, positions, *, cfg, window, knobs, collect_cache,
                       ffn, shard_fn):
-    h = rmsnorm(p["ln1"], x)
-    q, k, v = attn.qkv_project(p["attn"], h, positions, cfg.rope_theta)
+    h = norm(p["ln1"], x, cfg)
+    q, k, v = attn.qkv_project(p["attn"], h, positions, _rope_theta(cfg))
     q = shard_fn("attn_q", q)
     k = shard_fn("attn_kv", k)
     v = shard_fn("attn_kv", v)
@@ -158,7 +164,7 @@ def _apply_attn_block(p, x, positions, *, cfg, window, knobs, collect_cache,
                                        causal_skip=knobs.causal_skip)
     ctx = shard_fn("attn_out", ctx)
     x = x + attn.attn_output(p["attn"], ctx)
-    h2 = rmsnorm(p["ln2"], x)
+    h2 = norm(p["ln2"], x, cfg)
     aux = {}
     if ffn == "moe":
         out, aux = moe_ffn(p["moe"], h2, cfg.moe, train=not collect_cache,
@@ -178,7 +184,7 @@ def _apply_attn_block(p, x, positions, *, cfg, window, knobs, collect_cache,
 def _ffn_out(p, x, ffn, *, cfg, shard_fn):
     """Inference-time FFN sublayer (norm, FFN, residual) shared by the
     cached block bodies."""
-    h2 = rmsnorm(p["ln2"], x)
+    h2 = norm(p["ln2"], x, cfg)
     if ffn == "moe":
         out, _ = moe_ffn(p["moe"], h2, cfg.moe, train=False, shard_fn=shard_fn)
     elif ffn == "mlp":
@@ -207,12 +213,13 @@ def _attn_decode(p, x, cache, pos, *, cfg, window, knobs, shard_fn,
     are written to the cache first, and the attention mask is causal
     within the block as well as against the prefix."""
     b, t = x.shape[0], x.shape[1]
-    h = rmsnorm(p["ln1"], x)
+    h = norm(p["ln1"], x, cfg)
     pos = jnp.asarray(pos, jnp.int32)  # scalar (lockstep) or (B,) (ragged)
     positions = jnp.broadcast_to(
         (pos.reshape(-1, 1) if pos.ndim else pos) + jnp.arange(t)[None, :]
         if t > 1 else (pos.reshape(-1, 1) if pos.ndim else pos), (b, t))
-    q, k_new, v_new = attn.qkv_project(p["attn"], h, positions, cfg.rope_theta)
+    q, k_new, v_new = attn.qkv_project(p["attn"], h, positions,
+                                       _rope_theta(cfg))
     if paged is not None:
         page_idx, page_size = paged
         quant = "k_scale" in cache  # quantized pools carry scale leaves
@@ -289,9 +296,10 @@ def _attn_prefill_chunk(p, x, cache, slot, offset, *, cfg, window, knobs,
       the parity oracle for both fast paths.
     """
     c = x.shape[1]
-    h = rmsnorm(p["ln1"], x)
+    h = norm(p["ln1"], x, cfg)
     positions = offset + jnp.arange(c)[None, :]
-    q, k_new, v_new = attn.qkv_project(p["attn"], h, positions, cfg.rope_theta)
+    q, k_new, v_new = attn.qkv_project(p["attn"], h, positions,
+                                       _rope_theta(cfg))
     if paged is not None:
         page_idx, page_size = paged
         quant = "k_scale" in cache

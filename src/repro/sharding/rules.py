@@ -109,13 +109,16 @@ def _trailing_spec(pstr: str, key: str, shape, mesh, fsdp: bool,
                      (None, None, None))
     if key in ("w_gate", "w_up"):  # mlp (dm, ff)
         return c((fs, "model"), (None, "model"), (None, None))
+    if key == "b_up":  # (ff,): with w_up's columns
+        return c(("model",), (None,))
     if key == "w_down":  # (ff, dm)
         return c(("model", fs), ("model", None), (None, None))
     if key == "in_proj":  # (dm, d_in)
         return c((fs, "model"), (None, "model"), (None, None))
     if key == "out_proj":  # (di, dm)
         return c(("model", fs), ("model", None), (None, None))
-    # conv_w, conv_b, A_log, dt_bias, D, norm scales, biases: replicate
+    # conv_w, conv_b, A_log, dt_bias, D, norm scales and biases, the other
+    # biases (bo, b_down): replicate
     return P(*([None] * rank))
 
 
@@ -123,6 +126,7 @@ _SEMANTIC_RANK = {
     "table": 2, "head": 2, "wq": 3, "wk": 3, "wv": 3, "wo": 3,
     "bq": 2, "bk": 2, "bv": 2, "router": 2, "in_proj": 2, "out_proj": 2,
     "w_gate": 2, "w_up": 2, "w_down": 2,  # dense MLP (moe overrides to 3)
+    "bo": 1, "b_up": 1, "b_down": 1, "bias": 1,  # proj_bias, LayerNorm
     "conv_w": 2, "conv_b": 1, "A_log": 1, "dt_bias": 1, "D": 1,
     "norm_scale": 1, "scale": 1,
 }
@@ -226,7 +230,10 @@ def _serve_trailing_spec(pstr: str, key: str, shape, mesh) -> P:
         return c(("model", None, None), (None, None, None))
     if key in ("w_gate", "w_up"):  # mlp (dm, ff): columns independent
         return c((None, "model"), (None, None))
-    # wo, w_down, router, embed table/head, norms, ssm leaves: replicated —
+    if key == "b_up":  # (ff,): with w_up's columns
+        return c(("model",), (None,))
+    # wo, w_down, router, embed table/head, the position table, norms,
+    # the other biases, ssm leaves: replicated —
     # these feed (or are) the contractions that must keep reduction order
     return P(*([None] * len(shape)))
 

@@ -4,7 +4,8 @@ No chip is used: ``jax.experimental.topologies`` describes a v5e:2x2 host
 and the TPU compiler installed with libtpu compiles for one of its chips.
 This catches what interpret mode cannot — a tile Mosaic refuses, too much
 VMEM, a step that does not fit one chip's HBM.  Widths are internlm2-1.8b's
-(16 query / 8 KV heads, head dim 128) with 16-token pages.
+(16 query / 8 KV heads, head dim 128) with 16-token pages; the ``_mqa``
+kernel cases take granite-20b's (48 query heads over one KV head).
 
 The topology is described inside a module fixture, never at import: only
 one process may load libtpu, and pytest-xdist workers import every test
@@ -58,17 +59,23 @@ def _compile(fn, *args):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _kernel_args(name, sds, b=B, n_pool=POOL):
+def _kernel_args(name, sds, b=B, n_pool=POOL, h=H, kv=KV,
+                 max_pages=MAX_PAGES, chunk=32):
     i32, bf16, f32 = jnp.int32, jnp.bfloat16, jnp.float32
-    pool = (n_pool, PS, KV, D)
-    table, pos = sds((b, MAX_PAGES), i32), sds((b,), i32)
+    pool = (n_pool, PS, kv, D)
+    table, pos = sds((b, max_pages), i32), sds((b,), i32)
     if name == "paged_decode_chat":  # the chat cell's engine and pool
         return _kernel_args("paged_decode", sds, b=30, n_pool=3361)
+    if "_mqa" in name:  # granite-20b's backlog cell: 48 query heads over
+        # one KV head, 32 slots x 8,192 positions, its fitted pool
+        return _kernel_args(name.replace("_mqa", ""), sds, b=32,
+                            n_pool=16385, h=48, kv=1, max_pages=512,
+                            chunk=256)
     if name.startswith("paged_decode"):
         tq = 4 if name.endswith("tq4") else 1
         splits = 4 if "splitk" in name else 1
         dt = jnp.int8 if "int8" in name else bf16
-        args = [sds((b, tq, H, D), bf16), sds(pool, dt), sds(pool, dt),
+        args = [sds((b, tq, h, D), bf16), sds(pool, dt), sds(pool, dt),
                 table, pos]
         if dt == bf16:
             def fn(q, k, v, t, p):
@@ -86,7 +93,7 @@ def _kernel_args(name, sds, b=B, n_pool=POOL):
         def fn(q, k, v, t, slot, off):
             return ops.paged_prefill_attention(q, k, v, t, slot, off,
                                                interpret=False)
-        return fn, [sds((1, 32, H, D), bf16), sds(pool, bf16),
+        return fn, [sds((1, chunk, h, D), bf16), sds(pool, bf16),
                     sds(pool, bf16), table, sds((), i32), sds((), i32)]
     if name == "dense_decode":
         def fn(q, k, v, p):
@@ -106,7 +113,8 @@ def _kernel_args(name, sds, b=B, n_pool=POOL):
 @pytest.mark.parametrize("name", [
     "paged_decode", "paged_decode_tq4", "paged_decode_int8",
     "paged_decode_splitk", "paged_decode_int8_splitk", "paged_prefill",
-    "dense_decode", "flash", "paged_decode_chat"])
+    "dense_decode", "flash", "paged_decode_chat", "paged_decode_mqa",
+    "paged_decode_mqa_splitk", "paged_prefill_mqa"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
